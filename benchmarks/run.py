@@ -197,35 +197,15 @@ def bench_service():
           f"{out['speedup_fused_vs_ref_1t']:.2f}x")
 
     # --- core sharded executor: 1/2/4 shards, deferred merge -------------
-    # shard_map needs >1 device; rather than force a multi-device host
-    # platform on THIS process (which would split the XLA:CPU thread pool
-    # and slow every other row), the executor rows run in a subprocess
-    # with --xla_force_host_platform_device_count=4 when the current
-    # backend is single-device CPU.
+    # The rows need 4 devices in THIS process.  A child process cannot
+    # supply them: this process already holds the backend (on a chip host,
+    # the chip itself), so the rows run here or not at all.
     if jax.device_count() >= 4:
         out.update(_executor_rows())
     else:
-        import subprocess
-        from repro.platform import subprocess_env
-        env = subprocess_env(4)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (os.path.join(os.path.dirname(HERE), "src"),
-                        env.get("PYTHONPATH")) if p)
-        proc = subprocess.run(
-            [sys.executable, "-c",
-             "import json, sys, os; sys.path.insert(0, os.environ['_BENCH_DIR']);"
-             "from run import _executor_rows;"
-             "print('EXECUTOR_JSON ' + json.dumps(_executor_rows()))"],
-            env={**env, "_BENCH_DIR": HERE}, capture_output=True, text=True)
-        rows = {}
-        for line in proc.stdout.splitlines():
-            if line.startswith("EXECUTOR_JSON "):
-                rows = json.loads(line[len("EXECUTOR_JSON "):])
-            else:
-                print(line)
-        if not rows:
-            print(f"executor subprocess failed:\n{proc.stderr[-2000:]}")
-        out.update(rows)
+        print(f"executor rows skipped: {jax.device_count()} device(s), the "
+              "sharded executor needs 4 (on the CPU start the run with "
+              "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
 
     out.update(_query_rows())
     return out
@@ -322,8 +302,7 @@ def _query_rows():
 
 def _executor_rows():
     """ShardedIngest throughput at 1/2/4 shards (run where >= 4 devices
-    exist; on CPU the service bench spawns this in a forced-multi-device
-    subprocess)."""
+    exist)."""
     import jax
     from repro.core import sjpc
     from repro.core.sjpc import SJPCConfig
@@ -584,6 +563,8 @@ def bench_roofline():
 
 def main(argv):
     os.makedirs(OUT_DIR, exist_ok=True)
+    from repro.platform import enable_compile_cache
+    enable_compile_cache()
     # REPRO_PLUGINS=examples.plugins adds plugin estimator kinds: suites
     # that enumerate estimators.available() (equal_space) pick them up
     # automatically, so plugin rows land in the collated report

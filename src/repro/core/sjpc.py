@@ -280,7 +280,7 @@ class ShardedIngest:
     N micro-batches cost one reduction, not N.
 
     When the runtime exposes at least ``num_shards`` devices the per-shard
-    update runs inside :func:`repro.compat.shard_map` over a 1-D 'shards'
+    update runs inside :func:`jax.shard_map` over a 1-D 'shards'
     mesh with the delta states and record slices sharded on the leading
     axis; with fewer devices the identical computation runs as a ``vmap``
     over the shard axis (bit-identical counters -- the update is integer
@@ -357,7 +357,6 @@ class ShardedIngest:
             return jax.jit(step)
 
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
 
         def local(deltas, values, row_mask, keys):
             # local views carry a leading shard axis of size 1
@@ -366,10 +365,10 @@ class ShardedIngest:
                 values[0], row_mask[0], keys[0])
             return SJPCState(st.counters[None], st.n[None], st.step[None])
 
-        step = shard_map(local, mesh=self._mesh,
-                         in_specs=(P("shards"), P("shards"), P("shards"),
-                                   P("shards")),
-                         out_specs=P("shards"), check_rep=False)
+        step = jax.shard_map(local, mesh=self._mesh,
+                             in_specs=(P("shards"), P("shards"), P("shards"),
+                                       P("shards")),
+                             out_specs=P("shards"), check_vma=False)
         return jax.jit(step)
 
     # ------------------------------------------------------------------

@@ -6,8 +6,9 @@ generation step of Algorithm 1, fully dense (no gathers; excluded columns
 are `where`-skipped using the static combination-mask table).
 
 Tiling: grid (B_tiles, M_tiles); each kernel instance holds a
-(block_b, d) slab of records and a (block_m, d) slab of combination masks in
-VMEM and emits a (block_b, block_m) fingerprint tile.  d is a static python
+(block_b, d) slab of records and a (d, block_m) slab of (transposed)
+combination masks in VMEM and emits a (block_b, block_m) fingerprint tile;
+the two fingerprint bases are scalars in SMEM.  d is a static python
 loop (d <= ~12 for SJPC's practical regime, paper §9).
 """
 from __future__ import annotations
@@ -17,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import addmod_p31, mulmod_p31, reduce_p31
 
@@ -24,17 +26,32 @@ DEFAULT_BLOCK_B = 256
 DEFAULT_BLOCK_M = 256
 
 
-def _kernel(values_ref, masks_ref, ids_ref, bases_ref, out1_ref, out2_ref, *, d: int):
-    values = reduce_p31(values_ref[...])                 # (BB, d)
-    seed = addmod_p31(reduce_p31(ids_ref[...]), jnp.uint32(1))   # (BM,)
-    for which, out_ref in ((0, out1_ref), (1, out2_ref)):
-        base = bases_ref[which]
-        fp = jnp.broadcast_to(seed[None, :], (values.shape[0], seed.shape[0]))
-        for col in range(d):
-            v = addmod_p31(values[:, col:col + 1], jnp.uint32(1))     # (BB, 1)
+def horner_fingerprints(values, masks_t, ids, bases_ref):
+    """Both masked-Horner fingerprints of a record block, inside a kernel.
+
+    values (BB, d) uint32 records; masks_t (d, M) combination masks
+    (transposed, so column ``col`` is a lane row); ids (1, M) combination
+    ids; bases_ref: the two fingerprint bases as an int32 SMEM table.
+    Returns (fp1, fp2), each (BB, M) uint32.
+    """
+    values = reduce_p31(values)
+    seed = addmod_p31(reduce_p31(ids), jnp.uint32(1))        # (1, M)
+    shape = (values.shape[0], seed.shape[1])
+    fps = []
+    for which in (0, 1):
+        base = bases_ref[which].astype(jnp.uint32)
+        fp = jnp.broadcast_to(seed, shape)
+        for col in range(masks_t.shape[0]):                  # d is static
+            v = addmod_p31(values[:, col:col + 1], jnp.uint32(1))
             nxt = addmod_p31(mulmod_p31(fp, base), v)
-            fp = jnp.where(masks_ref[...][None, :, col] != 0, nxt, fp)
-        out_ref[...] = fp
+            fp = jnp.where(masks_t[col:col + 1, :] != 0, nxt, fp)
+        fps.append(fp)
+    return tuple(fps)
+
+
+def _kernel(bases_ref, values_ref, masks_ref, ids_ref, out1_ref, out2_ref):
+    out1_ref[...], out2_ref[...] = horner_fingerprints(
+        values_ref[...], masks_ref[...], ids_ref[...], bases_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_m", "interpret"))
@@ -49,8 +66,8 @@ def fingerprint_pallas(values, combo_masks, combo_ids, bases,
     B, d = values.shape
     M = combo_ids.shape[0]
 
-    bb = min(block_b, max(B, 8))
-    bm = min(block_m, max(M, 128))
+    bb = min(block_b, max(-(-B // 8) * 8, 8))
+    bm = min(block_m, max(-(-M // 128) * 128, 128))
     pad_b = (-B) % bb
     pad_m = (-M) % bm
     if pad_b:
@@ -62,13 +79,13 @@ def fingerprint_pallas(values, combo_masks, combo_ids, bases,
     grid = (values.shape[0] // bb, combo_ids.shape[0] // bm)
     out_shape = (values.shape[0], combo_ids.shape[0])
     fp1, fp2 = pl.pallas_call(
-        functools.partial(_kernel, d=d),
+        _kernel,
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bb, d), lambda gb, gm: (gb, 0)),
-            pl.BlockSpec((bm, d), lambda gb, gm: (gm, 0)),
-            pl.BlockSpec((bm,), lambda gb, gm: (gm,)),
-            pl.BlockSpec((2,), lambda gb, gm: (0,)),
+            pl.BlockSpec((d, bm), lambda gb, gm: (0, gm)),
+            pl.BlockSpec((1, bm), lambda gb, gm: (0, gm)),
         ],
         out_specs=[
             pl.BlockSpec((bb, bm), lambda gb, gm: (gb, gm)),
@@ -79,5 +96,6 @@ def fingerprint_pallas(values, combo_masks, combo_ids, bases,
             jax.ShapeDtypeStruct(out_shape, jnp.uint32),
         ],
         interpret=interpret,
-    )(values, combo_masks, combo_ids, bases)
+    )(jnp.asarray(bases).astype(jnp.int32), values, combo_masks.T,
+      combo_ids[None, :])
     return fp1[:B, :M], fp2[:B, :M]

@@ -16,7 +16,7 @@ the output block is written once on the last kv step.  Causal tiles fully
 above the diagonal are skipped with pl.when.  GQA: the index_map for K/V
 divides the head index, so KV heads are never repeat-expanded in HBM.
 
-``interpret=True`` validates on CPU (this container); compiled path is the
+``interpret=True`` validates on the CPU; compiled path is the
 TPU target.  Oracle: models.attention.full_attention.
 """
 from __future__ import annotations
@@ -63,14 +63,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 1)
             s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new > 0.5 * NEG_INF)[:, None], p, 0.0)
+        m_prev = m_ref[...]                               # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(m_new > 0.5 * NEG_INF, p, 0.0)
         alpha = jnp.where(m_prev > 0.5 * NEG_INF,
                           jnp.exp(m_prev - m_new), 0.0)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -79,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -117,8 +117,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, iq, ik: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),       # running max m
-            pltpu.VMEM((block_q,), jnp.float32),       # normalizer l
+            pltpu.VMEM((block_q, 1), jnp.float32),     # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),     # normalizer l
             pltpu.VMEM((block_q, hd), jnp.float32),    # weighted accumulator
         ],
         interpret=interpret,

@@ -18,15 +18,13 @@ here it is ONE kernel launch over stacked samples:
   per cell:  the Hamming-match tile  M[a, b] = #{c : A[a, c] == B[b, c]}
              builds column-by-column on the VPU (d is static and small);
              pair validity (both slots live, a != b on the diagonal tile)
-             masks it, and the histogram bin counts come from ONE MXU
-             contraction -- ones(1, block_r^2) @ onehot(block_r^2, d+1) --
-             so the R^2-sized match matrix never leaves the chip.
+             masks it, and each of the d+1 histogram bins is an indicator
+             reduction of the tile, so the R^2-sized match matrix never
+             leaves the chip.
 
-Counts are exact: the per-tile one-hot contraction accumulates at most
-block_r^2 <= 2^14 in f32 (integral, < 2^24), and cross-tile accumulation is
-int32.  The pure-jnp fallback (kernels/ref.py:fused_pairs_ref) is
-bit-identical; both are tested against the O(n^2) numpy oracle
-(core/exact.py:brute_force_pair_counts) across depths/widths/empty inputs
+Counts are exact: every reduction is int32.  The pure-jnp fallback
+(kernels/ref.py:fused_pairs_ref) is bit-identical; both are tested against
+the O(n^2) numpy oracle (core/exact.py:brute_force_pair_counts) across depths/widths/empty inputs
 in tests/test_fused_pairs.py.
 
 The N grid axis is the batching surface for more than streams: the
@@ -42,6 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_R = 128
 
@@ -55,30 +54,31 @@ def _kernel(items_i_ref, items_j_ref, valid_i_ref, valid_j_ref, out_ref,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     a = items_i_ref[0]                                   # (BR, d) uint32
-    b = items_j_ref[0]                                   # (BR, d) uint32
-    # Hamming-match tile, column by column (d is static and tiny)
+    bt = items_j_ref[0]                                  # (d, BR) uint32
+    # Hamming-match tile, column by column (d is static and tiny): the i
+    # side is read as columns, the j side (passed transposed) as rows
     match = jnp.zeros((block_r, block_r), jnp.int32)
     for c in range(d):
-        match += (a[:, c:c + 1] == b[None, :, c]).astype(jnp.int32)
+        match += (a[:, c:c + 1] == bt[c:c + 1, :]).astype(jnp.int32)
 
     # pair validity: both slots live, and not the self-pair on the diagonal
     row = jax.lax.broadcasted_iota(jnp.int32, (block_r, block_r), 0) \
         + gi * block_r
     col = jax.lax.broadcasted_iota(jnp.int32, (block_r, block_r), 1) \
         + gj * block_r
-    ok = (valid_i_ref[0][:, None] != 0) & (valid_j_ref[0][None, :] != 0) \
-        & (row != col)
+    ok = (valid_i_ref[0] != 0) & (valid_j_ref[0] != 0) & (row != col)
+    match = jnp.where(ok, match, -1)                     # -1 = masked out
 
-    # bin into the histogram with one MXU contraction:
-    # ones(1, BR^2) @ onehot(BR^2, d+1); per-tile counts <= BR^2 < 2^24 so
-    # the f32 accumulation is exact, then int32 across tiles
-    flat = jnp.where(ok, match, -1).reshape(-1)          # -1 = masked out
-    onehot = (flat[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (flat.shape[0], d + 1), 1)
-              ).astype(jnp.float32)
-    counts = jnp.dot(jnp.ones((1, flat.shape[0]), jnp.float32), onehot,
-                     preferred_element_type=jnp.float32)  # (1, d+1)
-    out_ref[0, :] += counts[0].astype(jnp.int32)
+    # bin into the (1, d+1) histogram row: per level, a sublane then a lane
+    # reduction of the indicator tile (exact int32 counts)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, d + 1), 1)
+    hist = jnp.zeros((1, d + 1), jnp.int32)
+    for k in range(d + 1):
+        per_col = jnp.sum((match == k).astype(jnp.int32), axis=0,
+                          keepdims=True)                 # (1, BR)
+        hist += jnp.where(lane == k,
+                          jnp.sum(per_col, axis=1, keepdims=True), 0)
+    out_ref[0] += hist
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -87,8 +87,8 @@ def fused_pairs_pallas(items, valid, *, block_r: int = DEFAULT_BLOCK_R,
     """(N, R, d) samples x (N, R) validity -> (N, d+1) int32 histograms.
 
     out[i, k] = #ordered pairs (a != b, both valid) of stream i's sample
-    agreeing on exactly k columns.  ``interpret=True`` is the
-    CPU-correctness mode (this container); on real TPU pass interpret=False.
+    agreeing on exactly k columns.  ``interpret=True`` runs the kernel in
+    the Pallas interpreter (any backend); on a TPU pass interpret=False.
     """
     N, R, d = items.shape
     assert valid.shape == (N, R), (valid.shape, (N, R))
@@ -101,18 +101,24 @@ def fused_pairs_pallas(items, valid, *, block_r: int = DEFAULT_BLOCK_R,
         valid = jnp.pad(valid, ((0, 0), (0, pad_r)))
     r_pad = R + pad_r
 
+    # every operand and the output keep the stream axis out of their last
+    # two block dims: the j side and its validity travel as rows
+    # (transposed / (N, 1, R)), the i side's validity as a column
     tiles = r_pad // block_r
     kernel = functools.partial(_kernel, d=d, block_r=block_r)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(N, tiles, tiles),
         in_specs=[
             pl.BlockSpec((1, block_r, d), lambda n, gi, gj: (n, gi, 0)),
-            pl.BlockSpec((1, block_r, d), lambda n, gi, gj: (n, gj, 0)),
-            pl.BlockSpec((1, block_r), lambda n, gi, gj: (n, gi)),
-            pl.BlockSpec((1, block_r), lambda n, gi, gj: (n, gj)),
+            pl.BlockSpec((1, d, block_r), lambda n, gi, gj: (n, 0, gj)),
+            pl.BlockSpec((1, block_r, 1), lambda n, gi, gj: (n, gi, 0)),
+            pl.BlockSpec((1, 1, block_r), lambda n, gi, gj: (n, 0, gj)),
         ],
-        out_specs=pl.BlockSpec((1, d + 1), lambda n, gi, gj: (n, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, d + 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, d + 1), lambda n, gi, gj: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1, d + 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(items, items, valid, valid)
+    )(items, jnp.swapaxes(items, 1, 2), valid[:, :, None], valid[:, None, :])
+    return out[:, 0]

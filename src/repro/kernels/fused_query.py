@@ -11,13 +11,13 @@ estimator uses two different stacks sketched with identical hash params.
 The median over the depth axis and the lattice inversion are O(N*L*t)
 scalars and stay in the surrounding jit (`sjpc._estimate_batch_core`).
 
-  grid (N, L, w_tiles):
-    stream axis     -- parallel; each stream owns an (L, t, w) counter block
-    level axis      -- parallel; each level owns a (t, w) counter plane
-    width axis      -- innermost + sequential: the (t,) accumulator stays
-                      resident in VMEM while every (t, block_w) counter tile
-                      of the plane reduces into it (counters-squared
-                      reduction never leaves the chip)
+  grid (N / BLOCK_N, w_tiles):
+    stream axis     -- parallel; each step owns BLOCK_N streams' (L, t, w)
+                      counter blocks (all levels at once)
+    width axis      -- innermost + sequential: the (BLOCK_N, L, t)
+                      accumulator stays resident in VMEM while every
+                      (t, block_w) counter tile reduces into it
+                      (counters-squared reduction never leaves the chip)
 
 f32 products/sums are exact while every partial sum stays below 2^24 --
 the paper's O(log n)-bit counter analysis puts SJPC magnitudes well inside
@@ -34,20 +34,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_W = 2048
+BLOCK_N = 8                 # streams per grid step
 
 
 def _kernel(a_ref, b_ref, out_ref):
-    gw = pl.program_id(2)
+    gw = pl.program_id(1)
 
     @pl.when(gw == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    a = a_ref[0, 0].astype(jnp.float32)          # (t, block_w)
-    b = b_ref[0, 0].astype(jnp.float32)
-    out_ref[0, 0] += jnp.sum(a * b, axis=-1)     # (t,)
+    a = a_ref[...].astype(jnp.float32)           # (BN, L, t, block_w)
+    b = b_ref[...].astype(jnp.float32)
+    out_ref[...] += jnp.sum(a * b, axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -56,8 +58,8 @@ def fused_query_pallas(counters_a, counters_b, *,
                        interpret: bool = True):
     """(N, L, t, w) x (N, L, t, w) -> (N, L, t) float32 row moments.
 
-    ``interpret=True`` is the CPU-correctness mode (this container); on real
-    TPU pass interpret=False.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (any
+    backend); on a TPU pass interpret=False.
     """
     assert counters_a.shape == counters_b.shape, \
         (counters_a.shape, counters_b.shape)
@@ -65,14 +67,22 @@ def fused_query_pallas(counters_a, counters_b, *,
     bw = min(block_w, w)
     # widths are powers of two (sketch invariant), so any pow2 tile divides
     assert w % bw == 0, f"block_w={bw} must divide width w={w}"
-    return pl.pallas_call(
+    bn = min(BLOCK_N, N)
+    pad_n = (-N) % bn
+    if pad_n:                     # zero planes: moments 0, sliced off below
+        counters_a = jnp.pad(counters_a, ((0, pad_n), (0, 0), (0, 0), (0, 0)))
+        counters_b = jnp.pad(counters_b, ((0, pad_n), (0, 0), (0, 0), (0, 0)))
+    # the output keeps a trailing unit lane axis so its (t, 1) block tail
+    # equals the array tail, as the TPU block rule requires
+    spec = pl.BlockSpec((bn, L, t, bw), lambda i, gw: (i, 0, 0, gw))
+    out = pl.pallas_call(
         _kernel,
-        grid=(N, L, w // bw),
-        in_specs=[
-            pl.BlockSpec((1, 1, t, bw), lambda i, l, gw: (i, l, 0, gw)),
-            pl.BlockSpec((1, 1, t, bw), lambda i, l, gw: (i, l, 0, gw)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, t), lambda i, l, gw: (i, l, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, L, t), jnp.float32),
+        grid=((N + pad_n) // bn, w // bw),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((bn, L, t, 1), lambda i, gw: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N + pad_n, L, t, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(counters_a, counters_b)
+    return out[:N, ..., 0]

@@ -63,9 +63,10 @@ def sketch_update_pallas(counters, fp1, fp2, bucket_coeffs, sign_coeffs, weights
                          interpret: bool = True):
     """counters (t, w) int32 + flat keys (N,) -> updated (t, w) counters.
 
-    ``interpret=True`` is the CPU-correctness mode (this container); on real
-    TPU pass interpret=False.  N is padded to a block multiple with weight-0
-    elements; w must be a power of two (sketch invariant).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (any
+    backend); on a TPU pass interpret=False.  N is padded to a block
+    multiple with weight-0 elements; w must be a power of two (sketch
+    invariant).
     """
     t, w = counters.shape
     fp1 = fp1.reshape(-1)

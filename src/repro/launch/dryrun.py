@@ -25,7 +25,6 @@ import traceback
 import numpy as np
 import jax
 import jax.numpy as jnp
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import configs
@@ -137,7 +136,7 @@ def lower_train_cell(cfg, mesh, shape: configs.ShapeSpec, *,
                               ssm_chunk=ssm_chunk, seq_parallel=seq_parallel,
                               probs_dtype=(jnp.bfloat16 if probs_bf16
                                            else jnp.float32))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step_fn).lower(state_in, batch_in)
     return lowered, {"optimizer": opt_name, "params": cfg.param_count(),
                      "active_params": cfg.active_param_count()}
@@ -159,7 +158,7 @@ def lower_prefill_cell(cfg, mesh, shape: configs.ShapeSpec, *,
     fn = SV.make_prefill(cfg, dims, mesh, attn_chunk=attn_chunk,
                          ssm_chunk=ssm_chunk)
     ef = _enc_feats_spec(cfg, shape.batch, shape.seq, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if ef is not None:
             lowered = jax.jit(fn).lower(params_in, tokens, ef)
         else:
@@ -189,7 +188,7 @@ def lower_decode_cell(cfg, mesh, shape: configs.ShapeSpec, *,
     token = _sds((shape.batch, 1), jnp.int32,
                  NamedSharding(mesh, PartitionSpec(b_ax, None)))
     fn = SV.make_decode_step(cfg, dims, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(params_in, token, cache_in)
     return lowered, {"params": cfg.param_count()}
 

@@ -24,17 +24,41 @@ parse the post-SPMD HLO text ourselves:
     reduce-scatter + all-gather), times the loop multiplier.
 
 Shapes in the partitioned module are per-device, so all numbers are
-per-chip.  Hardware constants (TPU v5e per assignment): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+per-chip.  Hardware peaks come from :data:`PEAKS`, keyed by the device kind
+jax reports; the dry-run target (a described, not attached, chip) is
+:data:`DRYRUN_KIND`.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (one direction)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    ici_bw: float            # bytes/s per ICI link (one direction)
+
+
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links), keyed by
+# ``jax.devices()[i].device_kind``.  A kind missing here is an error, never
+# a default: a roofline share against another chip's peaks is meaningless.
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -341,11 +365,13 @@ class Roofline:
 
     @classmethod
     def build(cls, flops, hbm_bytes, wire_bytes, model_flops=0.0,
-              xla_flops_raw=0.0, legalization_bytes=0.0):
-        c = flops / PEAK_FLOPS
-        m = hbm_bytes / HBM_BW
-        n = wire_bytes / ICI_BW
-        m_tpu = max(hbm_bytes - legalization_bytes, 0.0) / HBM_BW
+              xla_flops_raw=0.0, legalization_bytes=0.0,
+              device_kind: str = DRYRUN_KIND):
+        peak = peaks_for(device_kind)
+        c = flops / peak.flops
+        m = hbm_bytes / peak.hbm_bw
+        n = wire_bytes / peak.ici_bw
+        m_tpu = max(hbm_bytes - legalization_bytes, 0.0) / peak.hbm_bw
         dom = max((("compute", c), ("memory", m), ("collective", n)),
                   key=lambda kv: kv[1])[0]
         return cls(flops=flops, hbm_bytes=hbm_bytes, wire_bytes=wire_bytes,
@@ -360,7 +386,11 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze_compiled(compiled, *, model_flops_per_device: float = 0.0) -> Roofline:
+def analyze_compiled(compiled, *, model_flops_per_device: float = 0.0,
+                     device_kind: str = DRYRUN_KIND) -> Roofline:
+    """Roofline of a compiled program against ``device_kind``'s peaks (the
+    live device's kind when the program ran on one; raises for a kind
+    :data:`PEAKS` does not know)."""
     cost = compiled.cost_analysis()
     if isinstance(cost, list):
         cost = cost[0]
@@ -369,7 +399,8 @@ def analyze_compiled(compiled, *, model_flops_per_device: float = 0.0) -> Roofli
                           parsed["total_wire_bytes"],
                           model_flops_per_device,
                           xla_flops_raw=float(cost.get("flops", 0.0)),
-                          legalization_bytes=parsed["legalization_bytes"])
+                          legalization_bytes=parsed["legalization_bytes"],
+                          device_kind=device_kind)
 
 
 def model_flops(cfg, n_tokens: int, *, train: bool) -> float:

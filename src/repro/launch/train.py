@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro import compat
 from repro.models import model as M
 from repro.models.config import ArchConfig, Dims
 from repro.models.layers import split_tree
@@ -88,7 +87,7 @@ def make_train_step(cfg: ArchConfig, dims: Dims, optimizer: Optimizer,
                                         tokens_blk, step)
             return c[None], n[None]
 
-        c, n = compat.shard_map(
+        c, n = jax.shard_map(
             local, mesh=mesh,
             in_specs=(PartitionSpec(bd, None, None, None),
                       PartitionSpec(bd),

@@ -130,10 +130,14 @@ def ssd_chunked(x, a, dt, bm, cm, *, chunk: int = DEFAULT_CHUNK, h0=None):
         cum = jnp.cumsum(ak, axis=1)        # inclusive (B,L,H)
         # ---- intra-chunk (quadratic in L) ----
         cb = jnp.einsum("bign,bjgn->bijg", ck, bk)             # (B,L,L,G)
-        decay = jnp.exp(cum[:, :, None, :] - cum[:, None, :, :])
         ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
         jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-        w = jnp.where((ii >= jj)[None, :, :, None], decay, 0.0)        # (B,i,j,H)
+        # mask the exponent, not the exponential: above the diagonal
+        # cum_i - cum_j > 0 and exp overflows, and the masked inf turns
+        # the gradient into 0 * inf = nan
+        w = jnp.exp(jnp.where((ii >= jj)[None, :, :, None],
+                              cum[:, :, None, :] - cum[:, None, :, :],
+                              -jnp.inf))                        # (B,i,j,H)
         if g > 1:
             scores = jnp.repeat(cb, hg, axis=3)                # (B,i,j,H)
         else:

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from repro.compat import shard_map
 
 from .adamw import Optimizer, clip_by_global_norm
 from .q8adam import quantize, dequantize, quantize_v, dequantize_v, QTensor
@@ -48,7 +47,7 @@ def make_q8adam_sharded(mesh, lr_fn, param_pspecs, *, b1=0.9, b2=0.95,
                         seed=23) -> Optimizer:
     axes = _all_axes(mesh)
     sspecs = state_pspecs(mesh, param_pspecs)
-    smap = functools.partial(shard_map, mesh=mesh, check_vma=False)
+    smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
 
     def local_init(params):
         qm = lambda p: quantize(jnp.zeros(p.shape, jnp.float32))

@@ -14,13 +14,18 @@ SNIPPETS.md), so call sites stop hand-rolling environment mutation:
     it via :func:`set_platform`.
   * :func:`force_host_device_count` / :func:`subprocess_env` -- the
     forced-multi-device idiom: N XLA host devices on CPU for shard_map
-    testing/benchmarking, either in-process (before jax init) or as an
-    environment for a child process (how benchmarks/run.py executes its
-    executor rows).
+    testing, either in-process (before jax init) or as an environment for
+    a child process (the distributed harness's CPU workers).
+  * :func:`enable_compile_cache` -- the persistent compilation cache at a
+    fixed path, for entry points that compile large programs.
 """
 from __future__ import annotations
 
 import os
+import pathlib
+
+# the checkout root (src/repro/platform.py -> ../..)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
 
 # <https://jax.readthedocs.io/en/latest/gpu_performance_tips.html>
 GPU_XLA_FLAGS = (
@@ -85,3 +90,21 @@ def subprocess_env(n_devices: int, base: dict | None = None) -> dict:
     env = dict(os.environ if base is None else base)
     force_host_device_count(n_devices, env)
     return env
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: the path is part of every entry's key, so a
+    directory that moved between runs would never hit.  Call before the
+    first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = str(CHECKOUT / ".jax_cache")
+    import jax
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

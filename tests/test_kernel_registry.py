@@ -416,3 +416,26 @@ class TestPlatformBootstrap:
         from repro import platform as plat
         assert "--xla_gpu_enable_triton_softmax_fusion=true" \
             in plat.GPU_XLA_FLAGS
+
+    @pytest.mark.parametrize("env_dir", [None, "from-env"])
+    def test_compile_cache_location(self, monkeypatch, tmp_path, env_dir):
+        """The cache goes where JAX_COMPILATION_CACHE_DIR says (and nothing
+        is set in code), else to the fixed <checkout>/.jax_cache."""
+        from repro import platform as plat
+        before = jax.config.jax_compilation_cache_dir
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
+        try:
+            path = plat.enable_compile_cache()
+            if env_dir is None:
+                assert path == str(plat.CHECKOUT / ".jax_cache")
+                assert jax.config.jax_compilation_cache_dir == path
+                assert (plat.CHECKOUT / "src" / "repro").is_dir()
+            else:
+                assert path == str(tmp_path / env_dir)
+                assert jax.config.jax_compilation_cache_dir == before
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

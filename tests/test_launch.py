@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec
 
-from repro import compat, configs
+from repro import configs
 from repro.models import model as M
 from repro.models.config import compute_dims
 from repro.models.layers import split_tree
@@ -54,7 +54,7 @@ def test_train_step_runs_on_debug_mesh():
         "labels": jnp.asarray(np.random.default_rng(1).integers(
             0, cfg.vocab_size, size=(4, 32), dtype=np.int32)),
     }
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state2, metrics = jax.jit(step_fn)(state, batch)
     assert np.isfinite(float(metrics["loss"]))
     assert int(state2.step) == 1
@@ -141,6 +141,17 @@ def test_roofline_terms():
     assert r.collective_s == pytest.approx(0.25)
     assert r.dominant == "compute"
     assert r.useful_ratio == pytest.approx(0.5)
+
+
+def test_roofline_peaks_by_device_kind():
+    """Peaks come from the device kind; an unknown kind raises instead of
+    silently borrowing v5e's rates."""
+    assert RL.peaks_for("TPU v5 lite").flops == 197e12
+    with pytest.raises(ValueError, match="no published peak"):
+        RL.peaks_for("TPU v9 imaginary")
+    with pytest.raises(ValueError):
+        RL.Roofline.build(flops=1.0, hbm_bytes=1.0, wire_bytes=0.0,
+                          device_kind="cpu")
 
 
 def test_cost_analysis_available():

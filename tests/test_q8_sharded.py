@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro import compat
 from repro.checkpoint import save_checkpoint, restore_checkpoint
 from repro.launch.mesh import make_debug_mesh
 from repro.optim.q8sharded import make_q8adam_sharded, state_pspecs
@@ -35,7 +34,7 @@ def test_q8_sharded_matches_unsharded_semantics():
     opt = make_q8adam_sharded(mesh, constant(0.05), _pspecs(),
                               weight_decay=0.0)
     ref = make_adamw(constant(0.05), weight_decay=0.0)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_q = opt.init(params)
         s_r = ref.init(params)
         p_q, p_r = params, dict(params)

@@ -86,3 +86,19 @@ class TestSSD:
         y2, _ = ssd_chunked(x2, a_strong, dt, bm, cm, chunk=4)
         np.testing.assert_allclose(np.asarray(y[:, 6:]), np.asarray(y2[:, 6:]),
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_chunked_gradient_finite_under_strong_decay():
+    """Within a chunk the decay cum_i - cum_j grows past exp's f32 range
+    above the diagonal; those entries are masked, and must stay masked in
+    the backward pass too (no 0 * inf = nan gradients)."""
+    x, _, dt, bm, cm = _rand(3, s=64)
+    a = jnp.full(dt.shape, -4.0, jnp.float32)     # cum spans ~250 per chunk
+
+    def loss(x, a):
+        y, h = ssd_chunked(x, a, dt, bm, cm, chunk=64)
+        return jnp.sum(y) + jnp.sum(h)
+
+    gx, ga = jax.grad(loss, argnums=(0, 1))(x, a)
+    assert np.isfinite(np.asarray(gx)).all()
+    assert np.isfinite(np.asarray(ga)).all()
