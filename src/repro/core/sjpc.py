@@ -31,6 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import child
+
 from . import projections as proj
 from . import sketch as sk
 from .fingerprint import make_fingerprint_bases, subvalue_fingerprints
@@ -610,7 +612,8 @@ def estimate_batch(cfg: SJPCConfig, counters, n, *, clamp: bool = True,
                                    interpret=interpret)
     y, x, g, n = (np.asarray(jax.device_get(a), np.float64)
                   for a in (y, x, g, n))
-    on, off = _batch_bounds(cfg, n, g)
+    with child("query.bounds", streams=counters.shape[0]):
+        on, off = _batch_bounds(cfg, n, g)
     return SJPCBatchEstimate(x=x, g=g, y=y, n=n, stderr=on, stderr_offline=off)
 
 
@@ -631,7 +634,9 @@ def estimate_join_batch(cfg: SJPCConfig, counters_a, counters_b, n_a, n_b, *,
                                    use_pallas=use_pallas, interpret=interpret)
     y, x, g, n_a, n_b = (np.asarray(jax.device_get(a), np.float64)
                          for a in (y, x, g, n_a, n_b))
-    on, off = _batch_bounds(cfg, np.maximum(n_a, n_b), np.maximum(g, 1.0))
+    with child("query.bounds", streams=N):
+        on, off = _batch_bounds(cfg, np.maximum(n_a, n_b),
+                                np.maximum(g, 1.0))
     return SJPCBatchEstimate(x=x, g=g, y=y, n=np.stack([n_a, n_b], axis=1),
                              stderr=on, stderr_offline=off)
 

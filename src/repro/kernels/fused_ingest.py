@@ -155,5 +155,6 @@ def fused_ingest_pallas(counters, values, masks, ids, bases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="sjpc_fused_ingest",
     )(_as_i32(bases), _as_i32(bucket_coeffs), _as_i32(sign_coeffs),
       values, masks_t, ids, weights, counters)

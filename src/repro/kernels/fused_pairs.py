@@ -120,5 +120,6 @@ def fused_pairs_pallas(items, valid, *, block_r: int = DEFAULT_BLOCK_R,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="sjpc_fused_pairs",
     )(items, jnp.swapaxes(items, 1, 2), valid[:, :, None], valid[:, None, :])
     return out[:, 0]

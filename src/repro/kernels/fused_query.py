@@ -84,5 +84,6 @@ def fused_query_pallas(counters_a, counters_b, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sjpc_fused_query",
     )(counters_a, counters_b)
     return out[:N, ..., 0]

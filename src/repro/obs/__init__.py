@@ -9,7 +9,8 @@ Three parts, composable and individually injectable:
   trace.py     nested :class:`Tracer` spans with wall *and* device time
                (``Span.sync`` blocks on registered jax outputs before the
                clock stops), JSON-lines events, optional
-               ``jax.profiler.TraceAnnotation`` bracketing
+               ``jax.profiler.TraceAnnotation`` bracketing; ``child``
+               opens a span under whichever span is open on this thread
   accuracy.py  :class:`AccuracyAuditor` -- opt-in sampled replay of
                queried windows through ``core/exact.py``, serving live
                rel-err and CI-coverage counters per estimator kind
@@ -25,8 +26,8 @@ import dataclasses
 from .accuracy import AccuracyAuditor
 from .metrics import (DEFAULT_BUCKETS, Histogram, MetricsRegistry,
                       NULL_REGISTRY, default_registry, set_default_registry)
-from .trace import (NULL_SPAN, NULL_TRACER, Span, Tracer, default_tracer,
-                    set_default_tracer)
+from .trace import (NULL_SPAN, NULL_TRACER, Span, Tracer, child,
+                    default_tracer, set_default_tracer)
 
 
 @dataclasses.dataclass
@@ -62,6 +63,6 @@ _DISABLED = Observability(metrics=NULL_REGISTRY, tracer=NULL_TRACER)
 __all__ = [
     "AccuracyAuditor", "DEFAULT_BUCKETS", "Histogram", "MetricsRegistry",
     "NULL_REGISTRY", "NULL_SPAN", "NULL_TRACER", "Observability", "Span",
-    "Tracer", "default_registry", "default_tracer", "set_default_registry",
-    "set_default_tracer",
+    "Tracer", "child", "default_registry", "default_tracer",
+    "set_default_registry", "set_default_tracer",
 ]

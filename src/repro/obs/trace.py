@@ -31,6 +31,12 @@ histogram when given one (``histogram=``), which is how every
 
 Disabled tracers hand out one shared no-op span -- no allocation, no
 clock reads -- honoring the obs-off overhead contract.
+
+Code that holds no :class:`Observability` (``core/``, the estimators)
+opens its spans with :func:`child`: a span on the tracer whose span is
+innermost open on this thread, nested under it, or the no-op span when no
+span is open -- so core code is timed inside a service flush or poll, and
+costs one thread-local read anywhere else.
 """
 from __future__ import annotations
 
@@ -42,6 +48,13 @@ import time
 from .metrics import MetricsRegistry
 
 _EVENT_RING = 1024           # in-memory events kept per tracer
+_OPEN = threading.local()    # spans open on this thread, any tracer
+
+
+def _open_spans() -> list:
+    if not hasattr(_OPEN, "spans"):
+        _OPEN.spans = []
+    return _OPEN.spans
 
 
 class Span:
@@ -76,6 +89,7 @@ class Span:
     # -- context manager ------------------------------------------------
     def __enter__(self):
         self._tracer._stack().append(self.name)
+        _open_spans().append(self)
         if self._tracer.annotate:
             import jax
             self._annotation = jax.profiler.TraceAnnotation(self.path)
@@ -95,6 +109,9 @@ class Span:
         stack = self._tracer._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
+        opened = _open_spans()
+        if opened and opened[-1] is self:
+            opened.pop()
         if exc_type is None:
             self._tracer._emit(self)
             if self._histogram:
@@ -184,6 +201,18 @@ class Tracer:
             if self._sink is not None and self._sink_path is not None:
                 self._sink.close()
                 self._sink = None
+
+
+def child(name: str, **attrs):
+    """A span nested under the innermost span open on this thread, on that
+    span's tracer; ``NULL_SPAN`` when no span is open.  For code that is
+    handed no :class:`Observability`: it adds no argument to the
+    estimator protocol, and the span it opens times host work only (it
+    registers nothing to sync)."""
+    opened = getattr(_OPEN, "spans", None)
+    if not opened:
+        return NULL_SPAN
+    return opened[-1]._tracer.span(name, **attrs)
 
 
 NULL_TRACER = Tracer(enabled=False)

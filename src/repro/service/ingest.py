@@ -60,7 +60,6 @@ from repro.obs import Observability
 from .registry import HashGroup, StreamEntry
 
 _INGEST_SALT = 0x5E41CE
-_EMPTY = np.zeros((0,))          # shape probe for absent pending entries
 
 
 def ingest_key(cfg: SJPCConfig, uid: int, round_idx: int) -> jax.Array:
@@ -262,32 +261,36 @@ class IngestPipeline:
             return
 
         S = len(entries)
-        values = np.zeros((rounds, S, B, cfg.d), np.uint32)
-        mask = np.zeros((rounds, S, B), np.int32)
-        round_idx = np.zeros((rounds, S), np.int32)
-        for i, e in enumerate(entries):
-            rows = pending.get(e.name, np.zeros((0, cfg.d), np.uint32))
-            for r in range(rounds):
-                chunk = rows[r * B:(r + 1) * B]
-                values[r, i, :chunk.shape[0]] = chunk
-                mask[r, i, :chunk.shape[0]] = 1
-                self.stats["padded_rows"] += B - chunk.shape[0]
-            # streams with no pending records ride along fully masked (the
-            # cohort's S stays jit-shape-stable) but neither consume round
-            # keys nor commit the ride-along state below: their window
-            # content is unchanged, and committing the step-only bump
-            # would spuriously bump the version and thrash version-keyed
-            # query caches.  Each stream's replay coordinate advances only
-            # by the rounds that carried ITS rows (r_i = ceil(c_i / B)) --
-            # trailing rounds that exist only for a busier cohort-mate are
-            # fully masked for this stream, consume no randomness, and must
-            # not shift its key stream, or the window content would depend
-            # on co-tenants' backlog sizes and the offline replay contract
-            # (module docstring) would break
-            round_idx[:, i] = e.flushes + np.arange(rounds)
-            if rows.shape[0]:
-                e.flushes += -(-rows.shape[0] // B)
-                e.records += int(rows.shape[0])
+        with self.obs.span("ingest.coalesce", streams=S,
+                           rounds=rounds) as sp:
+            values = np.zeros((rounds, S, B, cfg.d), np.uint32)
+            mask = np.zeros((rounds, S, B), np.int32)
+            round_idx = np.zeros((rounds, S), np.int32)
+            sp.set(bytes=values.nbytes + mask.nbytes + round_idx.nbytes)
+            for i, e in enumerate(entries):
+                rows = pending.get(e.name, np.zeros((0, cfg.d), np.uint32))
+                for r in range(rounds):
+                    chunk = rows[r * B:(r + 1) * B]
+                    values[r, i, :chunk.shape[0]] = chunk
+                    mask[r, i, :chunk.shape[0]] = 1
+                # streams with no pending records ride along fully masked
+                # (the cohort's S stays jit-shape-stable) but neither
+                # consume round keys nor commit the ride-along state below:
+                # their window content is unchanged, and committing the
+                # step-only bump would spuriously bump the version and
+                # thrash version-keyed query caches.  Each stream's replay
+                # coordinate advances only by the rounds that carried ITS
+                # rows (r_i = ceil(c_i / B)) -- trailing rounds that exist
+                # only for a busier cohort-mate are fully masked for this
+                # stream, consume no randomness, and must not shift its key
+                # stream, or the window content would depend on
+                # co-tenants' backlog sizes and the offline replay contract
+                # (module docstring) would break
+                round_idx[:, i] = e.flushes + np.arange(rounds)
+                if rows.shape[0]:
+                    e.flushes += -(-rows.shape[0] // B)
+                    e.records += int(rows.shape[0])
+        self.stats["padded_rows"] += S * B * rounds - sum(counts)
 
         gid, kind = self.group.group_id, entries[0].estimator_kind
         with self.obs.span("ingest.flush_cohort",
@@ -295,13 +298,19 @@ class IngestPipeline:
                            labels={"group": gid, "kind": kind},
                            group=gid, kind=kind, streams=S,
                            rounds=rounds) as sp:
-            keys = ingest_key_grid(
-                jnp.uint32(est.ingest_seed),
-                jnp.asarray([e.uid for e in entries], jnp.int32),
-                jnp.asarray(round_idx))
-            states = stack_states([out[e.name] for e in entries])
-            states = est.ingest_rounds(states, jnp.asarray(values),
-                                       jnp.asarray(mask), keys)
+            # stack before the upload: the stack's per-stream temporaries
+            # are freed before the record block lands on the device
+            with self.obs.span("ingest.stack", streams=S):
+                states = stack_states([out[e.name] for e in entries])
+            with self.obs.span("ingest.upload",
+                               bytes=values.nbytes + mask.nbytes
+                               + round_idx.nbytes):
+                keys = ingest_key_grid(
+                    jnp.uint32(est.ingest_seed),
+                    jnp.asarray([e.uid for e in entries], jnp.int32),
+                    jnp.asarray(round_idx))
+                values, mask = jnp.asarray(values), jnp.asarray(mask)
+            states = est.ingest_rounds(states, values, mask, keys)
             # device-time semantics: the span blocks on the dispatched
             # states before its clock stops (trace events show dispatch
             # vs compute separately)
@@ -315,6 +324,8 @@ class IngestPipeline:
             m.inc("ingest_rounds_total", rounds, group=gid, kind=kind)
             m.inc("ingest_dispatch_rows_total", S * B * rounds,
                   group=gid, kind=kind)
-        for i, e in enumerate(entries):
-            if pending.get(e.name, _EMPTY).shape[0]:
-                out[e.name] = index_state(states, i)
+        with self.obs.span("ingest.unstack",
+                           streams=sum(1 for c in counts if c)):
+            for i, e in enumerate(entries):
+                if counts[i]:
+                    out[e.name] = index_state(states, i)

@@ -175,9 +175,11 @@ class Snapshot:
                                         "kind": views[0].kind, "op": "self"},
                                 group=group_id, kind=views[0].kind,
                                 streams=len(views)) as sp:
+                with self._obs.span("query.stack", streams=len(views)):
+                    states = stack_states([v.state for v in views])
                 est = views[0].estimator.estimate_batch(
-                    stack_states([v.state for v in views]), clamp=clamp,
-                    use_pallas=self._use_pallas, interpret=self._interpret)
+                    states, clamp=clamp, use_pallas=self._use_pallas,
+                    interpret=self._interpret)
                 sp.sync(*jax.tree_util.tree_leaves(est))
             self._cache[key] = ({v.name: i for i, v in enumerate(views)}, est)
         self._local[local_key] = self._cache_get(key)
@@ -214,9 +216,11 @@ class Snapshot:
                                     "op": "self"},
                             group="+".join(gids), kind=kind,
                             streams=len(views), cohorts=len(todo)) as sp:
+            with self._obs.span("query.stack", streams=len(views)):
+                states = stack_states([v.state for v in views])
             est = views[0].estimator.estimate_batch(
-                stack_states([v.state for v in views]), clamp=clamp,
-                use_pallas=self._use_pallas, interpret=self._interpret)
+                states, clamp=clamp, use_pallas=self._use_pallas,
+                interpret=self._interpret)
             sp.sync(*jax.tree_util.tree_leaves(est))
         lo = 0
         for c in todo:
@@ -239,11 +243,13 @@ class Snapshot:
                             histogram="query_batch_seconds",
                             labels={"group": gid, "kind": kind, "op": "join"},
                             group=gid, kind=kind, pairs=len(pairs)) as sp:
+            with self._obs.span("query.stack", streams=len(views_a)):
+                states_a = stack_states([v.state for v in views_a])
+            with self._obs.span("query.stack", streams=len(views_b)):
+                states_b = stack_states([v.state for v in views_b])
             est = views_a[0].estimator.estimate_join_batch(
-                stack_states([v.state for v in views_a]),
-                stack_states([v.state for v in views_b]),
-                clamp=clamp, use_pallas=self._use_pallas,
-                interpret=self._interpret)
+                states_a, states_b, clamp=clamp,
+                use_pallas=self._use_pallas, interpret=self._interpret)
             sp.sync(*jax.tree_util.tree_leaves(est))
         for i, (va, vb) in enumerate(zip(views_a, views_b)):
             k = ("join", va.name, va.version, vb.name, vb.version, clamp)

@@ -263,14 +263,16 @@ class EstimationService:
                            group=group_id, streams=len(entries)):
             t0 = time.perf_counter()
             new_states = pipe.flush(entries)
-            for e in entries:
-                e.window.absorb_delta(new_states[e.name])
+            with self.obs.span("window.commit", streams=len(entries)):
+                for e in entries:
+                    e.window.absorb_delta(new_states[e.name])
             # jax dispatch is asynchronous: without blocking on the
             # committed windows this timed the *enqueue* and reported
             # near-zero.  flush_s is device-inclusive wall time, obs on
             # or off (the span's histogram inherits the same interval)
-            jax.block_until_ready(
-                [jax.tree_util.tree_leaves(e.window.total) for e in entries])
+            with self.obs.span("window.block", streams=len(entries)):
+                jax.block_until_ready([jax.tree_util.tree_leaves(
+                    e.window.total) for e in entries])
             self.stats["flush_s"] += time.perf_counter() - t0
 
     def flush(self) -> None:

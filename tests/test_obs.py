@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sjpc import SJPCConfig
-from repro.obs import (Histogram, MetricsRegistry, Observability, Tracer,
-                       default_registry, set_default_registry)
+from repro.obs import (NULL_SPAN, Histogram, MetricsRegistry, Observability,
+                       Tracer, child, default_registry, set_default_registry)
 from repro.service import ContinuousQuery, EstimationService, ServiceConfig
 
 CFG = SJPCConfig(d=6, s=4, width=256, depth=2, seed=3)
@@ -289,6 +289,187 @@ class TestServiceInstrumentation:
             in text
         assert 'estimator_memory_bytes{kind="sjpc",stream="t"}' in text
         assert "service_poll_seconds_count 2" in text
+
+
+# ---------------------------------------------------------------------------
+# child spans inside a flush and a poll
+# ---------------------------------------------------------------------------
+
+# op -> {child path: parent path}: every span a toy flush or poll opens
+# inside its top-level span
+CHILD_PATHS = {
+    "flush": {
+        "service.flush/ingest.coalesce": "service.flush",
+        "service.flush/ingest.flush_cohort": "service.flush",
+        "service.flush/ingest.flush_cohort/ingest.upload":
+            "service.flush/ingest.flush_cohort",
+        "service.flush/ingest.flush_cohort/ingest.stack":
+            "service.flush/ingest.flush_cohort",
+        "service.flush/ingest.unstack": "service.flush",
+        "service.flush/window.commit": "service.flush",
+        "service.flush/window.block": "service.flush",
+    },
+    "poll": {
+        "service.poll/query.self_batch/query.stack":
+            "service.poll/query.self_batch",
+        "service.poll/query.self_batch/query.bounds":
+            "service.poll/query.self_batch",
+        "service.poll/query.join_batch/query.stack":
+            "service.poll/query.join_batch",
+        "service.poll/query.join_batch/query.bounds":
+            "service.poll/query.join_batch",
+    },
+}
+
+
+def _toy_cycle(svc, rng):
+    """One flush and one poll over three streams, two of them joined."""
+    for name in ("a", "b", "c"):
+        svc.ingest(name, _records(100, rng))
+    svc.flush()
+    return svc.poll()
+
+
+def _toy_service(**bundle_kw):
+    svc, obs = _service(**bundle_kw)
+    for name in ("a", "b", "c"):
+        svc.create_stream(name, "g")
+    svc.register_continuous(ContinuousQuery("all", "all_thresholds", ("a",)))
+    svc.register_continuous(ContinuousQuery("ab", "join", ("a", "b")))
+    return svc, obs
+
+
+def _contains(outer: dict, inner: dict) -> bool:
+    slack = 1e-6                       # ts is rounded to the microsecond
+    return (outer["ts"] - slack <= inner["ts"]
+            and inner["ts"] + inner["total_ms"] * 1e-3
+            <= outer["ts"] + outer["total_ms"] * 1e-3 + slack)
+
+
+class TestChildSpans:
+    @pytest.mark.parametrize("op", ["flush", "poll"])
+    def test_new_paths_nest_under_their_parents(self, op):
+        svc, obs = _toy_service()
+        rng = np.random.default_rng(11)
+        _toy_cycle(svc, rng)
+        n0 = len(obs.tracer.events)
+        _toy_cycle(svc, rng)
+        events = list(obs.tracer.events)[n0:]
+        top = "service." + op
+        tops = [e for e in events if e["path"] == top]
+        assert len(tops) == 1
+        want = CHILD_PATHS[op]
+        got = {e["path"] for e in events if _contains(tops[0], e)}
+        assert set(want) <= got, sorted(set(want) - got)
+        for e in events:
+            if e["path"] in want:
+                parents = [p for p in events if p["path"] == want[e["path"]]
+                           and _contains(p, e)]
+                assert len(parents) == 1, e
+                assert e["depth"] == parents[0]["depth"] + 1
+                assert "streams" in e or "bytes" in e, e
+        if op == "flush":
+            up = next(e for e in events if e["name"] == "ingest.upload")
+            co = next(e for e in events if e["name"] == "ingest.coalesce")
+            # 2 rounds of (3 streams, 64 rows): values, mask, round indices
+            assert co["bytes"] == up["bytes"] == 2 * 3 * 64 * (6 * 4 + 4) \
+                + 2 * 3 * 4
+            assert co["streams"] == 3 and co["rounds"] == 2
+
+    @pytest.mark.parametrize("op", ["flush", "poll"])
+    def test_children_sum_to_no_more_than_their_parent(self, op):
+        svc, obs = _toy_service()
+        rng = np.random.default_rng(13)
+        _toy_cycle(svc, rng)
+        n0 = len(obs.tracer.events)
+        _toy_cycle(svc, rng)
+        events = list(obs.tracer.events)[n0:]
+        want = CHILD_PATHS[op]
+        seen = 0
+        for parent in set(want.values()):
+            for p in (e for e in events if e["path"] == parent):
+                kids = [e for e in events if want.get(e["path"]) == parent
+                        and _contains(p, e)]
+                assert kids, parent
+                # each event's total_ms is rounded to 0.1 us
+                assert sum(k["total_ms"] for k in kids) \
+                    <= p["total_ms"] + 1e-4 * len(kids), (p, kids)
+                seen += 1
+        assert seen >= 2
+
+    def test_child_with_no_open_span_is_the_null_span(self):
+        assert child("orphan") is NULL_SPAN
+        tr = Tracer()
+        with tr.span("outer"):
+            with child("inner", rows=2) as sp:
+                assert sp is not NULL_SPAN
+        assert child("orphan") is NULL_SPAN    # closed again
+        assert [e["path"] for e in tr.events] == ["outer/inner", "outer"]
+        assert tr.events[0]["rows"] == 2
+
+    @pytest.mark.parametrize("observe", [True, False])
+    def test_child_inside_a_poll_follows_observe(self, monkeypatch, observe):
+        """Core code opens ``query.bounds`` with ``child``: a span under
+        the batch span when the service observes, the no-op span when it
+        does not."""
+        from repro.core import sjpc
+        seen = []
+        bounds = sjpc._batch_bounds
+
+        def spy(*a, **kw):
+            seen.append(child("probe"))
+            return bounds(*a, **kw)
+
+        monkeypatch.setattr(sjpc, "_batch_bounds", spy)
+        svc = EstimationService(ServiceConfig(batch_rows=64,
+                                              observe=observe),
+                                obs=None if observe else
+                                Observability.disabled())
+        svc.create_group("g", CFG)
+        svc.create_stream("t", "g")
+        svc.register_continuous(ContinuousQuery("q", "all_thresholds",
+                                                ("t",)))
+        svc.ingest("t", _records(64))
+        svc.poll()
+        assert seen
+        assert all((s is NULL_SPAN) != observe for s in seen)
+
+    def test_profiler_annotations_match_span_events(self, tmp_path):
+        """With ``annotate=True`` the host plane of the profile holds one
+        annotation per span event: same path, order, nesting, and a
+        duration within 1 ms."""
+        from jax.profiler import ProfileData
+        svc, obs = _toy_service(annotate=True)
+        rng = np.random.default_rng(12)
+        _toy_cycle(svc, rng)                 # compile outside the profile
+        n0 = len(obs.tracer.events)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _toy_cycle(svc, rng)
+        finally:
+            jax.profiler.stop_trace()
+        events = sorted(list(obs.tracer.events)[n0:],
+                        key=lambda e: (e["ts"], e["depth"]))
+        paths = {e["path"] for e in events}
+        assert set(CHILD_PATHS["flush"]) | set(CHILD_PATHS["poll"]) <= paths
+        (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        notes = sorted(
+            ((e.start_ns, -e.duration_ns, e.name, e.duration_ns)
+             for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name in paths))
+        assert [n[2] for n in notes] == [e["path"] for e in events]
+        open_: list = []
+        for start, _, name, dur in notes:
+            while open_ and open_[-1][1] <= start:
+                open_.pop()
+            parent = open_[-1][0] if open_ else None
+            assert parent == (name.rsplit("/", 1)[0] if "/" in name
+                              else None), (name, parent)
+            open_.append((name, start + dur))
+        for (_, _, name, dur), e in zip(notes, events):
+            assert abs(dur * 1e-6 - e["total_ms"]) <= 1.0, (name, e)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every xdist
 worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -121,3 +123,17 @@ def test_every_pallas_tpu_registration_is_covered():
            if PALLAS_TPU in {i.name for i in kernel_registry().impls(op)}}
     covered = {case.split("/")[0] for case in CASES}
     assert ops <= covered, ops - covered
+
+
+@pytest.mark.parametrize("case,name", [
+    ("fused_ingest/round", "sjpc_fused_ingest"),
+    ("fused_query/joins", "sjpc_fused_query"),
+    ("fused_pairs/reservoir", "sjpc_fused_pairs"),
+])
+def test_service_kernels_carry_stable_names(one_chip, case, name):
+    """The device trace names an op by its HLO instruction: the service's
+    kernels pass ``name=``, so the op reads ``<name>.<n>`` in every build
+    and not a name derived from the traced function."""
+    fn, shapes = CASES[case]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), case
