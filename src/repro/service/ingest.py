@@ -30,8 +30,14 @@ directly and is bit-compatible with the PR 1 single-device pipeline.
 Shapes are static: records are coalesced into rounds of exactly
 ``batch_rows`` rows per stream, the tail round padded with zero rows that
 carry row_mask 0 (contributing nothing to counters or n -- see
-``sjpc.update``).  jit compiles once per (R, S, batch_rows) and reuses the
-executable across flushes of the same shape.
+``sjpc.update``).  Only the streams with pending records dispatch: the A
+active streams of a cohort of S are compacted, in uid order, into the
+smallest power-of-two bucket S_d >= A (capped at S), the S_d - A pad slots
+fully masked and their outputs dropped.  jit compiles once per
+(R, S_d, batch_rows) -- at most ceil(log2 S) + 1 stream buckets -- and
+reuses the executable across flushes of the same shape.  The update is a
+vmap over streams, so a stream's result does not depend on which others
+share its dispatch.
 
 Double buffering: ``submit`` appends to the *front* buffer while ``flush``
 drains the *back* buffer; the buffers swap at flush start.  In-process this
@@ -217,12 +223,14 @@ class IngestPipeline:
         windowed sample estimators -- whatever ``window.ingest_base``
         hands out).
 
-        Streams dispatch in **estimator cohorts**: every stream of one
-        estimator kind shares one batched ``ingest_rounds`` call (static S
-        per cohort for jit shape stability); streams with no remaining
-        records ride along fully masked.  An all-SJPC group is exactly the
-        PR 2 single-dispatch path, bit for bit.  ``entry.flushes`` counts
-        the rounds that carried the stream's OWN rows, and is the replay
+        Streams dispatch in **estimator cohorts**: the streams of one
+        estimator kind that have pending records share one batched
+        ``ingest_rounds`` call over a power-of-two bucket of stream slots
+        (module docstring); streams with no records are left out of the
+        dispatch and keep their state, window version and replay
+        coordinate.  An all-SJPC group is exactly one
+        :func:`multi_round_update` dispatch.  ``entry.flushes`` counts the
+        rounds that carried the stream's OWN rows, and is the replay
         coordinate for :func:`ingest_key` -- cohort rounds that existed only
         for a busier cohort-mate are fully masked here, consume none of this
         stream's randomness, and do not advance it.
@@ -254,60 +262,63 @@ class IngestPipeline:
                       out: dict) -> None:
         B, cfg = self.batch_rows, self.group.cfg
         est = entries[0].estimator
-        counts = [pending.get(e.name, np.zeros((0, cfg.d), np.uint32)).shape[0]
-                  for e in entries]
-        rounds = max((-(-c // B) for c in counts if c), default=0)
-        if rounds == 0:
+        active = [(e, pending[e.name]) for e in entries
+                  if e.name in pending and pending[e.name].shape[0]]
+        if not active:
             return
+        rounds = max(-(-rows.shape[0] // B) for _, rows in active)
+        # only the A streams with records dispatch, padded to a power-of-two
+        # bucket of S_d slots: jit compiles once per (rounds, S_d), at most
+        # ceil(log2 S) + 1 stream buckets, and A == S dispatches the cohort
+        S, A = len(entries), len(active)
+        S_d = min(1 << (A - 1).bit_length(), S)
+        n_records = sum(rows.shape[0] for _, rows in active)
 
-        S = len(entries)
-        with self.obs.span("ingest.coalesce", streams=S,
+        with self.obs.span("ingest.coalesce", streams=S_d, active=A,
                            rounds=rounds) as sp:
-            values = np.zeros((rounds, S, B, cfg.d), np.uint32)
-            mask = np.zeros((rounds, S, B), np.int32)
-            round_idx = np.zeros((rounds, S), np.int32)
+            values = np.zeros((rounds, S_d, B, cfg.d), np.uint32)
+            mask = np.zeros((rounds, S_d, B), np.int32)
+            round_idx = np.zeros((rounds, S_d), np.int32)
             sp.set(bytes=values.nbytes + mask.nbytes + round_idx.nbytes)
-            for i, e in enumerate(entries):
-                rows = pending.get(e.name, np.zeros((0, cfg.d), np.uint32))
-                for r in range(rounds):
+            for i, (e, rows) in enumerate(active):
+                own = -(-rows.shape[0] // B)
+                for r in range(own):
                     chunk = rows[r * B:(r + 1) * B]
                     values[r, i, :chunk.shape[0]] = chunk
                     mask[r, i, :chunk.shape[0]] = 1
-                # streams with no pending records ride along fully masked
-                # (the cohort's S stays jit-shape-stable) but neither
-                # consume round keys nor commit the ride-along state below:
-                # their window content is unchanged, and committing the
-                # step-only bump would spuriously bump the version and
-                # thrash version-keyed query caches.  Each stream's replay
-                # coordinate advances only by the rounds that carried ITS
-                # rows (r_i = ceil(c_i / B)) -- trailing rounds that exist
-                # only for a busier cohort-mate are fully masked for this
+                # a stream's replay coordinate advances only by the rounds
+                # that carried ITS rows -- trailing rounds that exist only
+                # for a busier cohort-mate are fully masked for this
                 # stream, consume no randomness, and must not shift its key
                 # stream, or the window content would depend on
                 # co-tenants' backlog sizes and the offline replay contract
                 # (module docstring) would break
                 round_idx[:, i] = e.flushes + np.arange(rounds)
-                if rows.shape[0]:
-                    e.flushes += -(-rows.shape[0] // B)
-                    e.records += int(rows.shape[0])
-        self.stats["padded_rows"] += S * B * rounds - sum(counts)
+                e.flushes += own
+                e.records += int(rows.shape[0])
+        self.stats["padded_rows"] += S_d * B * rounds - n_records
 
         gid, kind = self.group.group_id, entries[0].estimator_kind
         with self.obs.span("ingest.flush_cohort",
                            histogram="ingest_flush_seconds",
                            labels={"group": gid, "kind": kind},
-                           group=gid, kind=kind, streams=S,
+                           group=gid, kind=kind, streams=S_d, active=A,
                            rounds=rounds) as sp:
             # stack before the upload: the stack's per-stream temporaries
-            # are freed before the record block lands on the device
-            with self.obs.span("ingest.stack", streams=S):
-                states = stack_states([out[e.name] for e in entries])
+            # are freed before the record block lands on the device.  The
+            # S_d - A pad slots reuse the first active stream's state
+            # object (no new arrays); their rows are all masked and their
+            # outputs are dropped
+            slots = [e for e, _ in active]
+            slots += [slots[0]] * (S_d - A)
+            with self.obs.span("ingest.stack", streams=S_d, active=A):
+                states = stack_states([out[e.name] for e in slots])
             with self.obs.span("ingest.upload",
                                bytes=values.nbytes + mask.nbytes
                                + round_idx.nbytes):
                 keys = ingest_key_grid(
                     jnp.uint32(est.ingest_seed),
-                    jnp.asarray([e.uid for e in entries], jnp.int32),
+                    jnp.asarray([e.uid for e in slots], jnp.int32),
                     jnp.asarray(round_idx))
                 values, mask = jnp.asarray(values), jnp.asarray(mask)
             states = est.ingest_rounds(states, values, mask, keys)
@@ -317,15 +328,18 @@ class IngestPipeline:
             sp.sync(*jax.tree_util.tree_leaves(states))
         self.stats["rounds"] += rounds
         self.stats["dispatches"] += 1
-        self.stats["dispatch_rows"] += S * B * rounds
+        self.stats["dispatch_rows"] += S_d * B * rounds
         m = self.obs.metrics
         if m.enabled:
             m.inc("ingest_dispatches_total", group=gid, kind=kind)
             m.inc("ingest_rounds_total", rounds, group=gid, kind=kind)
-            m.inc("ingest_dispatch_rows_total", S * B * rounds,
+            m.inc("ingest_dispatch_rows_total", S_d * B * rounds,
                   group=gid, kind=kind)
-        with self.obs.span("ingest.unstack",
-                           streams=sum(1 for c in counts if c)):
-            for i, e in enumerate(entries):
-                if counts[i]:
-                    out[e.name] = index_state(states, i)
+            m.inc("ingest_streams_skipped_total", S - A, group=gid,
+                  kind=kind)
+        # idle streams keep their ingest base: their window content and
+        # version are unchanged (committing a step-only bump would thrash
+        # version-keyed query caches)
+        with self.obs.span("ingest.unstack", streams=A):
+            for i, (e, _) in enumerate(active):
+                out[e.name] = index_state(states, i)

@@ -471,6 +471,28 @@ class TestChildSpans:
         for (_, _, name, dur), e in zip(notes, events):
             assert abs(dur * 1e-6 - e["total_ms"]) <= 1.0, (name, e)
 
+    def test_flush_counts_and_spans_only_the_active_streams(self):
+        """5 of 12 streams have records: the dispatch holds the 8-slot
+        bucket, and 7 streams are counted as skipped."""
+        svc, obs = _service()
+        for i in range(12):
+            svc.create_stream(f"s{i}", "g")
+        rng = np.random.default_rng(17)
+        svc.ingest("s0", _records(100, rng))            # 2 rounds of 64
+        for i in (3, 4, 7, 11):
+            svc.ingest(f"s{i}", _records(30, rng))
+        n0 = len(obs.tracer.events)
+        svc.flush()
+        m = obs.metrics
+        assert m.counter("ingest_streams_skipped_total", group="g",
+                         kind="sjpc") == 12 - 5
+        assert m.counter("ingest_dispatch_rows_total", group="g",
+                         kind="sjpc") == 8 * 64 * 2
+        events = list(obs.tracer.events)[n0:]
+        for name in ("ingest.flush_cohort", "ingest.stack"):
+            (e,) = [e for e in events if e["name"] == name]
+            assert (e["streams"], e["active"]) == (8, 5), e
+
 
 # ---------------------------------------------------------------------------
 # version-keyed query-cache accounting (satellite: cache telemetry)

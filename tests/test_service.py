@@ -493,9 +493,9 @@ class TestDriverServiceClient:
 class TestVersionStabilityAcrossCohortFlush:
     """The ingest pipeline must not thrash version-keyed query caches:
     a flush that carries no records for a stream -- even when cohort
-    mates DO flush and the stream rides along fully masked for jit shape
-    stability -- leaves that stream's window version (and flush replay
-    coordinate) untouched."""
+    mates DO flush and the stream is left out of their dispatch -- leaves
+    that stream's window version (and flush replay coordinate)
+    untouched."""
 
     def _build(self, estimator="sjpc"):
         cfg = SJPCConfig(d=4, s=3, ratio=1.0, width=128, depth=2, seed=7)
