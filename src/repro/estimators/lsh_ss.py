@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sjpc import SJPCConfig
+from repro.obs.trace import child
 
 from . import uncertainty
 from .base import (EstimateTable, Estimator, merge_tagged_samples,
@@ -320,13 +321,12 @@ class LSHSSEstimator(Estimator):
             seed=self.cfg.seed, n=n, step=step,
             replicates=self.bootstrap)
 
-    def _table(self, counts, same_sim, same_tags, same_seen, cross_sim,
-               cross_tags, cross_seen, n, step) -> EstimateTable:
+    def _strata(self, counts, same_sim, same_tags, cross_sim, cross_tags,
+                n):
         """Vectorized numpy: stratum totals from the bucket counts, per-
         stratum level fractions from the pair reservoirs, Eq. of §2.3.
-        Error bars: the stratified bootstrap of DESIGN.md §14 (the bucket
-        totals are linear and near-exact; the pair-reservoir fractions
-        carry the sampling randomness)."""
+        Returns the (N, L) x and g tables, the same-stratum hit counts,
+        and the two stratum pair totals."""
         counts = counts.astype(np.float64)
         same_pairs = (counts * (counts - 1)).sum(axis=-1)       # ordered
         total = n * (n - 1)
@@ -346,25 +346,33 @@ class LSHSSEstimator(Estimator):
         x_full = f1 * same_pairs[:, None] + f2 * cross_pairs[:, None]
         x = x_full[:, self.s:]
         g = np.cumsum(x[:, ::-1], axis=1)[:, ::-1] + n[:, None]
-        stderr = self._stderr(same_sim, same_tags, same_seen, cross_sim,
-                              cross_tags, cross_seen, same_pairs,
-                              cross_pairs, n, step)
-        return EstimateTable(x=x, g=g, y=y1[:, self.s:], n=n,
-                             stderr=stderr, stderr_offline=stderr,
-                             stderr_kind=("bootstrap_stratified"
-                                          if self.bootstrap else "none"))
+        return x, g, y1, same_pairs, cross_pairs
 
     def estimate_batch(self, states, *, clamp: bool = True,
                        use_pallas: bool | None = None,
                        interpret: bool | None = None) -> EstimateTable:
+        """The stratum scaling of :meth:`_strata`; error bars from the
+        stratified bootstrap of DESIGN.md §14 (the bucket totals are
+        linear and near-exact; the pair-reservoir fractions carry the
+        sampling randomness)."""
         del clamp, use_pallas, interpret           # pure host-numpy math
-        get = lambda a: np.asarray(jax.device_get(a))
-        return self._table(get(states.counts), get(states.same_sim),
-                           get(states.same_tags), get(states.same_seen),
-                           get(states.cross_sim), get(states.cross_tags),
-                           get(states.cross_seen),
-                           get(states.n).astype(np.float64),
-                           get(states.step))
+        fields = ("counts", "same_sim", "same_tags", "same_seen",
+                  "cross_sim", "cross_tags", "cross_seen", "n", "step")
+        with child("query.strata", streams=len(states.n)):
+            st = dict(zip(fields, map(np.asarray, jax.device_get(
+                [getattr(states, f) for f in fields]))))
+            n = st["n"].astype(np.float64)
+            x, g, y1, same_pairs, cross_pairs = self._strata(
+                st["counts"], st["same_sim"], st["same_tags"],
+                st["cross_sim"], st["cross_tags"], n)
+        stderr = self._stderr(st["same_sim"], st["same_tags"],
+                              st["same_seen"], st["cross_sim"],
+                              st["cross_tags"], st["cross_seen"], same_pairs,
+                              cross_pairs, n, st["step"])
+        return EstimateTable(x=x, g=g, y=y1[:, self.s:], n=n,
+                             stderr=stderr, stderr_offline=stderr,
+                             stderr_kind=("bootstrap_stratified"
+                                          if self.bootstrap else "none"))
 
     def estimate_ref(self, state: LSHSSState, *,
                      clamp: bool = True) -> EstimateTable:

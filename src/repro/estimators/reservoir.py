@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from repro.core import exact
 from repro.core.sjpc import SJPCConfig
+from repro.obs.trace import child
 
 from . import uncertainty
 from .base import (EstimateTable, Estimator, merge_tagged_samples,
@@ -253,9 +254,11 @@ class ReservoirEstimator(Estimator):
         # re-uploading the sample per query); only the small outputs --
         # histogram, valid counts, n -- are fetched
         valid = (jnp.asarray(states.tags) >= 0).astype(jnp.int32)
-        hist = np.asarray(jax.device_get(fused_pairs(
-            states.items, valid, use_pallas=use_pallas, interpret=interpret,
-        ))).astype(np.float64)
+        N, R = valid.shape
+        with child("query.pairs", streams=N, slots=R):
+            hist = np.asarray(jax.device_get(fused_pairs(
+                states.items, valid, use_pallas=use_pallas,
+                interpret=interpret))).astype(np.float64)
         n = np.asarray(jax.device_get(states.n), np.float64)
         m = np.asarray(jax.device_get(valid.sum(axis=1)), np.float64)
         stderr = self._bootstrap_stderr(states.items, valid, states.n,

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.metrics import default_registry
+from repro.obs.trace import child
 
 DEFAULT_REPLICATES = 32     # bootstrap resamples B
 DEFAULT_ITEM_CAP = 256      # m-out-of-m cap b per replicate
@@ -159,34 +160,36 @@ def bootstrap_pair_stderr(items, valid, n, *, keys, s: int,
     items = jnp.asarray(items)
     N, R, d = items.shape
     L = d - s + 1
-    m = np.asarray(jax.device_get(jnp.sum(jnp.asarray(valid) != 0, axis=1)),
-                   np.float64)
     if replicates < 2 or R < 2:
         return np.zeros((N, L))
-    reg = default_registry()
-    if reg.enabled:
-        reg.inc("bootstrap_replicates_total", N * replicates,
-                method="bootstrap")
-    idx, rep_valid, b_sizes = resample_valid_slots(
-        keys, valid, replicates, item_cap)
-    # gather replicate items on device; ONE fused launch over the stacked
-    # (N, B) leading dims computes every replicate histogram
-    rep_items = jnp.take_along_axis(items[:, None, :, :],
-                                    idx[:, :, :, None], axis=2)
-    hists = np.asarray(jax.device_get(pair_fn(rep_items, rep_valid)),
-                       np.float64)                        # (N, B, d+1)
+    with child("query.bootstrap", streams=N, replicates=replicates,
+               method="bootstrap", slots=min(item_cap, R)):
+        m = np.asarray(jax.device_get(
+            jnp.sum(jnp.asarray(valid) != 0, axis=1)), np.float64)
+        reg = default_registry()
+        if reg.enabled:
+            reg.inc("bootstrap_replicates_total", N * replicates,
+                    method="bootstrap")
+        idx, rep_valid, b_sizes = resample_valid_slots(
+            keys, valid, replicates, item_cap)
+        # gather replicate items on device; ONE fused launch over the
+        # stacked (N, B) leading dims computes every replicate histogram
+        rep_items = jnp.take_along_axis(items[:, None, :, :],
+                                        idx[:, :, :, None], axis=2)
+        hists = np.asarray(jax.device_get(pair_fn(rep_items, rep_valid)),
+                           np.float64)                    # (N, B, d+1)
 
-    n = np.asarray(n, np.float64)
-    b_sizes = np.asarray(jax.device_get(b_sizes), np.float64)
-    scale_b = pair_scale(n, b_sizes)                          # (N,)
-    x_reps = hists[:, :, s:] * scale_b[:, None, None]         # (N, B, L)
-    stderr = suffix_stderr_from_reps(x_reps)
-    # m-out-of-m cap rescale (U-stat leading variance is O(1/m)) and the
-    # Serfling without-replacement correction
-    with np.errstate(divide="ignore", invalid="ignore"):
-        cap_scale = np.where(m >= 2, np.sqrt(
-            np.minimum(b_sizes, m) / np.maximum(m, 1.0)), 0.0)
-    return stderr * (cap_scale * serfling_factor(n, m))[:, None]
+        n = np.asarray(n, np.float64)
+        b_sizes = np.asarray(jax.device_get(b_sizes), np.float64)
+        scale_b = pair_scale(n, b_sizes)                      # (N,)
+        x_reps = hists[:, :, s:] * scale_b[:, None, None]     # (N, B, L)
+        stderr = suffix_stderr_from_reps(x_reps)
+        # m-out-of-m cap rescale (U-stat leading variance is O(1/m)) and
+        # the Serfling without-replacement correction
+        with np.errstate(divide="ignore", invalid="ignore"):
+            cap_scale = np.where(m >= 2, np.sqrt(
+                np.minimum(b_sizes, m) / np.maximum(m, 1.0)), 0.0)
+        return stderr * (cap_scale * serfling_factor(n, m))[:, None]
 
 
 def _resample_fracs(sim, valid, levels, rng, replicates: int):
@@ -232,27 +235,30 @@ def stratified_bootstrap_stderr(same_sim, same_valid, same_seen,
         raise ValueError("stratified bootstrap needs >= 2 replicates")
     levels = np.arange(d + 1)
     N = same_pairs.shape[0]
-    reg = default_registry()
-    if reg.enabled:
-        reg.inc("bootstrap_replicates_total", N * replicates,
-                method="bootstrap_stratified")
-    n_i = np.asarray(n, np.int64).reshape(N)
-    step_i = np.asarray(step, np.int64).reshape(N)
-    seen_s = np.asarray(same_seen, np.float64).reshape(N)
-    seen_c = np.asarray(cross_seen, np.float64).reshape(N)
-    x_dev = np.zeros((N, replicates, d + 1))
-    for i in range(N):
-        # per-stream rng keyed on (seed, n, step): a stream's error bar is
-        # independent of its position in a stacked cohort (batch == ref)
-        rng = np.random.default_rng(np.random.SeedSequence(
-            [int(np.uint32(seed) ^ np.uint32(_BOOT_SALT)),
-             int(n_i[i]) & 0xFFFFFFFF, int(step_i[i]) & 0xFFFFFFFF]))
-        for sim, valid, seen, pairs in (
-                (np.asarray(same_sim)[i], np.asarray(same_valid)[i],
-                 seen_s[i], same_pairs[i]),
-                (np.asarray(cross_sim)[i], np.asarray(cross_valid)[i],
-                 seen_c[i], cross_pairs[i])):
-            f, m = _resample_fracs(sim, valid, levels, rng, replicates)
-            dev = f - f.mean(axis=0, keepdims=True)            # (B, d+1)
-            x_dev[i] += dev * (pairs * serfling_factor(seen, m))
-    return suffix_stderr_from_reps(x_dev[:, :, s:])
+    with child("query.bootstrap", streams=N, replicates=replicates,
+               method="bootstrap_stratified"):
+        reg = default_registry()
+        if reg.enabled:
+            reg.inc("bootstrap_replicates_total", N * replicates,
+                    method="bootstrap_stratified")
+        n_i = np.asarray(n, np.int64).reshape(N)
+        step_i = np.asarray(step, np.int64).reshape(N)
+        seen_s = np.asarray(same_seen, np.float64).reshape(N)
+        seen_c = np.asarray(cross_seen, np.float64).reshape(N)
+        x_dev = np.zeros((N, replicates, d + 1))
+        for i in range(N):
+            # per-stream rng keyed on (seed, n, step): a stream's error bar
+            # is independent of its position in a stacked cohort
+            # (batch == ref)
+            rng = np.random.default_rng(np.random.SeedSequence(
+                [int(np.uint32(seed) ^ np.uint32(_BOOT_SALT)),
+                 int(n_i[i]) & 0xFFFFFFFF, int(step_i[i]) & 0xFFFFFFFF]))
+            for sim, valid, seen, pairs in (
+                    (np.asarray(same_sim)[i], np.asarray(same_valid)[i],
+                     seen_s[i], same_pairs[i]),
+                    (np.asarray(cross_sim)[i], np.asarray(cross_valid)[i],
+                     seen_c[i], cross_pairs[i])):
+                f, m = _resample_fracs(sim, valid, levels, rng, replicates)
+                dev = f - f.mean(axis=0, keepdims=True)        # (B, d+1)
+                x_dev[i] += dev * (pairs * serfling_factor(seen, m))
+        return suffix_stderr_from_reps(x_dev[:, :, s:])
