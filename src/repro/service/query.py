@@ -16,6 +16,16 @@ PR 1 per-stream numpy path (int64-exact F2 + float64 inversion per stream)
 is kept verbatim behind ``use_fused_query=False`` as the conformance
 oracle; tests/test_fused_query.py holds the two within 1e-6.
 
+The stack behind each self launch stays **resident** across polls
+(DESIGN.md §12.1): the engine keeps, per launch, the stacked states and
+the state object each row was built from.  A poll that misses the result
+cache restacks only the rows whose view state is a different object, in
+one jitted ``update_rows`` dispatch that writes them in place; it
+rebuilds the whole stack with ``stack_states`` only when the launch's
+membership changed, no stack is resident, or more than half the rows
+changed.  Either way the stack equals ``stack_states`` over the views,
+bit for bit, so the estimator sees the operands it saw before.
+
 Results are memoized in a cache shared across snapshots of one
 :class:`QueryEngine`, keyed by each stream's **window version** (bumped by
 `WindowedSketch` on every ingest commit and epoch rotation) -- so standing
@@ -36,10 +46,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 
 from repro.core.sjpc import SJPCConfig
 from repro.estimators import Estimator, stack_states
@@ -48,6 +60,23 @@ from repro.obs import Observability
 from .registry import StreamRegistry
 
 _CACHE_MAX_ENTRIES = 4096      # shared-cache bound; LRU-evicted beyond
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def update_rows(stacked, idx, *states):
+    """``stacked`` with row ``idx[j]`` replaced by ``states[j]``, leaf by
+    leaf.  The stacked buffers are donated, so the rows are written in
+    place (JAX copies instead where a host view still aliases a buffer).
+    Callers pad ``idx`` to a power of two by repeating its last row, which
+    bounds the executables at ceil(log2 N) + 1 per state shape."""
+    return jax.tree_util.tree_map(
+        lambda rows, *xs: rows.at[idx].set(jnp.stack(xs)), stacked, *states)
+
+
+class _ResidentStack(NamedTuple):
+    names: tuple               # the launch's ordered membership
+    sources: list              # the state object each row was built from
+    stacked: object            # the stacked state pytree
 
 
 class QueryResult(NamedTuple):
@@ -106,6 +135,7 @@ class Snapshot:
                  use_pallas: bool | None = None,
                  interpret: bool | None = None,
                  cache: dict | None = None,
+                 stacks: dict | None = None,
                  obs: Observability | None = None):
         self._views = views
         self._registry = registry
@@ -113,6 +143,7 @@ class Snapshot:
         self._use_pallas = use_pallas
         self._interpret = interpret
         self._cache = {} if cache is None else cache
+        self._stacks = stacks      # resident self-launch stacks, or None
         self._local: dict = {}     # per-snapshot memo of shared-cache hits
         self._obs = obs if obs is not None else Observability.disabled()
 
@@ -175,8 +206,8 @@ class Snapshot:
                                         "kind": views[0].kind, "op": "self"},
                                 group=group_id, kind=views[0].kind,
                                 streams=len(views)) as sp:
-                with self._obs.span("query.stack", streams=len(views)):
-                    states = stack_states([v.state for v in views])
+                states = self._stacked((self._cohort_key(views),), views,
+                                       group_id)
                 est = views[0].estimator.estimate_batch(
                     states, clamp=clamp, use_pallas=self._use_pallas,
                     interpret=self._interpret)
@@ -184,6 +215,62 @@ class Snapshot:
             self._cache[key] = ({v.name: i for i, v in enumerate(views)}, est)
         self._local[local_key] = self._cache_get(key)
         return self._local[local_key]
+
+    @staticmethod
+    def _cohort_key(views: list[_StreamView]) -> tuple:
+        v = views[0]
+        return (v.group_id, id(v.estimator), v.shape_sig)
+
+    def _stacked(self, launch: tuple, views: list[_StreamView],
+                 group: str):
+        """The stacked states of one self launch (``launch``: its ordered
+        cohort keys), kept resident across polls.  Rows whose view state
+        is not the object the row was built from are restacked; identity,
+        not (name, version), since a re-created stream restarts at
+        version 0.  The whole stack is rebuilt when the membership
+        differs, none is resident, or more than half the rows changed.
+        Subset snapshots (``stacks`` is None) stack afresh and keep
+        nothing, so they never evict a full cohort's stack."""
+        names = tuple(v.name for v in views)
+        N = len(views)
+        stacks = self._stacks
+        entry = None if stacks is None else stacks.get(launch)
+        with self._obs.span("query.stack", streams=N) as sp:
+            if entry is None or entry.names != names:
+                changed = list(range(N))
+            else:
+                changed = [i for i, (v, src) in enumerate(
+                    zip(views, entry.sources)) if v.state is not src]
+            full = 2 * len(changed) > N
+            if full:
+                if stacks is not None:
+                    # one resident copy per cohort: drop every stack that
+                    # shares a cohort before building the new one
+                    for k in [k for k in stacks if set(k) & set(launch)]:
+                        del stacks[k]
+                stacked = stack_states([v.state for v in views])
+            elif changed:
+                pad = (1 << (len(changed) - 1).bit_length()) - len(changed)
+                idx = changed + changed[-1:] * pad
+                stacked = update_rows(entry.stacked,
+                                      np.asarray(idx, np.int32),
+                                      *(views[i].state for i in idx))
+            else:
+                stacked = entry.stacked
+            if stacks is not None:
+                stacks[launch] = _ResidentStack(
+                    names, [v.state for v in views], stacked)
+            sp.set(changed=len(changed),
+                   mode="full" if full else "incremental")
+        m = self._obs.metrics
+        if m.enabled:
+            rows = N if full else len(changed)
+            kind = views[0].kind
+            m.inc("query_stack_rows_total", value=float(rows),
+                  group=group, kind=kind, mode="stacked")
+            m.inc("query_stack_rows_total", value=float(N - rows),
+                  group=group, kind=kind, mode="reused")
+        return stacked
 
     @staticmethod
     def _self_key(views: list[_StreamView], clamp: bool) -> tuple:
@@ -216,8 +303,9 @@ class Snapshot:
                                     "op": "self"},
                             group="+".join(gids), kind=kind,
                             streams=len(views), cohorts=len(todo)) as sp:
-            with self._obs.span("query.stack", streams=len(views)):
-                states = stack_states([v.state for v in views])
+            states = self._stacked(
+                tuple(self._cohort_key(c) for c in todo), views,
+                "+".join(gids))
             est = views[0].estimator.estimate_batch(
                 states, clamp=clamp, use_pallas=self._use_pallas,
                 interpret=self._interpret)
@@ -407,6 +495,8 @@ class QueryEngine:
         self._cache: collections.OrderedDict = collections.OrderedDict()
         self._cache_max = (_CACHE_MAX_ENTRIES if cache_max_entries is None
                            else cache_max_entries)
+        # launch cohort keys -> _ResidentStack, read by full snapshots
+        self._stacks: dict = {}
         self.obs = obs if obs is not None else Observability.disabled()
 
     def snapshot(self, names: list[str] | None = None) -> Snapshot:
@@ -443,4 +533,6 @@ class QueryEngine:
         return Snapshot(views, self._registry,
                         use_fused_query=self.use_fused_query,
                         use_pallas=self.use_pallas, interpret=self.interpret,
-                        cache=self._cache, obs=self.obs)
+                        cache=self._cache,
+                        stacks=self._stacks if names is None else None,
+                        obs=self.obs)

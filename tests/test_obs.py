@@ -375,6 +375,22 @@ class TestChildSpans:
             assert co["bytes"] == up["bytes"] == 2 * 3 * 64 * (6 * 4 + 4) \
                 + 2 * 3 * 4
             assert co["streams"] == 3 and co["rounds"] == 2
+        if op == "poll":
+            batch, stack = ("service.poll/query.self_batch",
+                            "service.poll/query.self_batch/query.stack")
+            # every stream changed: the cohort's stack is rebuilt
+            st, = [e for e in events if e["path"] == stack]
+            assert (st["mode"], st["changed"]) == ("full", 3)
+            # one stream changed: only its row is restacked
+            n1 = len(obs.tracer.events)
+            svc.ingest("c", _records(100, rng))
+            svc.poll()
+            events = list(obs.tracer.events)[n1:]
+            b, = [e for e in events if e["path"] == batch]
+            st, = [e for e in events if e["path"] == stack]
+            assert _contains(b, st) and st["depth"] == b["depth"] + 1
+            assert (st["mode"], st["changed"], st["streams"]) == \
+                ("incremental", 1, 3)
 
     @pytest.mark.parametrize("op", ["flush", "poll"])
     def test_children_sum_to_no_more_than_their_parent(self, op):
