@@ -60,17 +60,10 @@ def bench_kernels():
 
 
 def bench_service():
-    """Estimation-service numbers: fused vs reference ingest, tenant
-    scaling, shard scaling, and snapshot query latency (p50/p95).
+    """Estimation-service numbers: fused ingest, tenant scaling, shard
+    scaling, and snapshot query latency (p50/p95).
 
     Rows:
-      ingest_ref_1t          reference (per-level, unfused) update running
-                             inside the SAME scan'd pipeline -- the
-                             conformance oracle.  speedup_fused_vs_ref_1t
-                             therefore isolates the fused-update win only;
-                             the full delta vs the PR 1 per-round-dispatch
-                             pipeline is the cross-commit records/sec
-                             comparison of this row's history.
       ingest_fused_{S}t      fused path (one scan'd dispatch per flush,
                              fused fingerprint->sketch update), 1/4/16
                              tenants
@@ -79,8 +72,8 @@ def bench_service():
                              exposes enough devices; deferred merge)
       snapshot_*_{N}s        query-side rows (see _query_rows): whole-group
                              snapshot x all thresholds at 1/16/64 streams,
-                             fused batched engine (steady-state and
-                             cold-cache) vs the PR 2 per-stream numpy path
+                             fused batched engine, steady-state and
+                             cold-cache
     """
     import jax
     from repro.core import sjpc
@@ -95,9 +88,8 @@ def bench_service():
            "resolved_impls": kernel_registry().resolution()}
     records_per_tenant = 4096
 
-    def run_pipeline(tenants, *, use_fused, tag, trace_sink=None):
+    def run_pipeline(tenants, *, tag, trace_sink=None):
         svc = EstimationService(ServiceConfig(batch_rows=512, window_epochs=4,
-                                              use_fused=use_fused,
                                               trace_sink=trace_sink))
         svc.create_group("g", cfg)
         names = [f"t{i}" for i in range(tenants)]
@@ -129,7 +121,7 @@ def bench_service():
         dt = time.time() - t0
         total = records_per_tenant * tenants * cycles
         out[tag] = {
-            "tenants": tenants, "fused": use_fused, "records": total,
+            "tenants": tenants, "records": total,
             "seconds": dt, "records_per_sec": total / dt,
             "rounds": svc.describe()["groups"]["g"]["ingest"]["rounds"],
         }
@@ -137,13 +129,12 @@ def bench_service():
               f"({total} records, {dt:.2f}s)")
         return svc, names
 
-    run_pipeline(1, use_fused=False, tag="ingest_ref_1t")
     trace_path = os.path.join(OUT_DIR, "trace.jsonl")
     if os.path.exists(trace_path):
         os.remove(trace_path)        # the tracer sink appends
     for tenants in (1, 4, 16):
         svc, names = run_pipeline(
-            tenants, use_fused=True, tag=f"ingest_fused_{tenants}t",
+            tenants, tag=f"ingest_fused_{tenants}t",
             trace_sink=trace_path if tenants == 4 else None)
         if tenants == 4:
             for nm in names:
@@ -190,12 +181,6 @@ def bench_service():
                   f"cache-hit {out['query']['cache_hit_rate']:.2f} "
                   f"queue-peak {out['query']['queue_depth_peak']:.0f}")
 
-    out["speedup_fused_vs_ref_1t"] = (
-        out["ingest_fused_1t"]["records_per_sec"]
-        / out["ingest_ref_1t"]["records_per_sec"])
-    print(f"fused vs reference (1 tenant): "
-          f"{out['speedup_fused_vs_ref_1t']:.2f}x")
-
     # --- core sharded executor: 1/2/4 shards, deferred merge -------------
     # The rows need 4 devices in THIS process.  A child process cannot
     # supply them: this process already holds the backend (on a chip host,
@@ -215,7 +200,7 @@ def _query_rows():
     """Snapshot query latency: every stream x every threshold of one hash
     group, p50/p95 over repeated snapshots, at 1/16/64 streams.
 
-    Three engines answer the identical query set:
+    Two snapshot modes answer the identical query set:
 
       snapshot_fused_{N}s       the service default -- fused batched engine
                                 with the version-keyed cache shared across
@@ -227,19 +212,9 @@ def _query_rows():
                                 isolates the one-compiled-call batch compute
                                 (stack + device put + jit'd moments/
                                 inversion + host assembly).
-      snapshot_ref_{N}s         the PR 2 semantics: per-stream int64 numpy
-                                F2 + float64 Python inversion, recomputed
-                                every snapshot (PR 2 memoized per Snapshot
-                                object only, so its steady state IS the
-                                recompute) -- reproduced by a fresh
-                                reference engine per iteration.
-
-    ``speedup_fused_query_16s`` (the acceptance row) is steady-state fused
-    vs the PR 2 path; ``speedup_fused_query_cold_16s`` is the compute-only
-    ratio with no cache amortization.
     """
     from repro.core.sjpc import SJPCConfig
-    from repro.service import EstimationService, QueryEngine, ServiceConfig
+    from repro.service import EstimationService, ServiceConfig
 
     cfg = SJPCConfig(d=6, s=4, ratio=0.5, width=2048, depth=3, seed=11)
     svc = EstimationService(ServiceConfig(batch_rows=512, window_epochs=4))
@@ -280,8 +255,6 @@ def _query_rows():
         rows = {
             f"snapshot_fused_{n}s": lambda s: svc.engine.snapshot(s),
             f"snapshot_fused_cold_{n}s": cold_snapshot,
-            f"snapshot_ref_{n}s": lambda s: QueryEngine(
-                svc.registry, use_fused_query=False).snapshot(s),
         }
         for tag, mk in rows.items():
             p50, p95, p99 = measure(mk, sub)
@@ -291,12 +264,6 @@ def _query_rows():
             print(f"{tag:>24}: p50 {p50:7.2f}ms p95 {p95:7.2f}ms "
                   f"p99 {p99:7.2f}ms "
                   f"({n} streams x {thresholds} thresholds)")
-    for kind in ("", "cold_"):
-        sp = (out["snapshot_ref_16s"]["p50_ms"]
-              / out[f"snapshot_fused_{kind}16s"]["p50_ms"])
-        out[f"speedup_fused_query_{kind}16s"] = sp
-        print(f"fused{' (cold)' if kind else ''} vs per-stream reference "
-              f"(16 streams x all thresholds): {sp:.1f}x")
     return out
 
 
@@ -342,8 +309,8 @@ def bench_planner():
     """Planner suite (DESIGN.md §16): poll latency at 10/100/1000 standing
     queries over 8 hash groups x 8 streams (same derived config, so the
     planner fuses all touched group cohorts into ONE estimate_batch
-    launch), planner on vs off, with one group's windows churned between
-    polls so a poll is never a pure cache walk.
+    launch), with one group's windows churned between polls so a poll is
+    never a pure cache walk.
 
     The CI acceptance guard reads ``p95_ratio_1000q_vs_10q`` from
     results.json and requires <= 3x: serving cost must scale with device
@@ -358,50 +325,48 @@ def bench_planner():
     churn = rng.integers(0, 1000, size=(256, cfg.d), dtype=np.uint32)
     out = {}
     for n_queries in (10, 100, 1000):
-        for use_planner in (True, False):
-            svc = EstimationService(ServiceConfig(
-                batch_rows=256, window_epochs=None,
-                use_planner=use_planner))
-            names = []
-            for g in range(groups):
-                svc.create_group(f"g{g}", cfg)
-                for s in range(per_group):
-                    nm = f"g{g}/s{s}"
-                    svc.create_stream(nm, f"g{g}")
-                    names.append(nm)
-            for i in range(n_queries):
-                svc.register_continuous(ContinuousQuery(
-                    f"q{i}", "self_join", (names[i % len(names)],)))
-            for nm in names:
-                svc.ingest(nm, churn)
-            svc.flush()
-            # warmup: compile + build the plan, then one churned poll so
-            # the steady-state launch shape (just g0's cohort) is compiled
-            # before timing starts
-            svc.poll()
+        svc = EstimationService(ServiceConfig(batch_rows=256,
+                                              window_epochs=None))
+        names = []
+        for g in range(groups):
+            svc.create_group(f"g{g}", cfg)
+            for s in range(per_group):
+                nm = f"g{g}/s{s}"
+                svc.create_stream(nm, f"g{g}")
+                names.append(nm)
+        for i in range(n_queries):
+            svc.register_continuous(ContinuousQuery(
+                f"q{i}", "self_join", (names[i % len(names)],)))
+        for nm in names:
+            svc.ingest(nm, churn)
+        svc.flush()
+        # warmup: compile + build the plan, then one churned poll so
+        # the steady-state launch shape (just g0's cohort) is compiled
+        # before timing starts
+        svc.poll()
+        svc.ingest(names[0], churn)
+        svc.flush()
+        svc.poll()
+        lats = []
+        for _ in range(15):
+            # touch g0 (covered by every query count) so each measured
+            # poll recomputes that cohort -- steady-state serving with
+            # live ingest, not a pure cache walk
             svc.ingest(names[0], churn)
             svc.flush()
+            t0 = time.time()
             svc.poll()
-            lats = []
-            for _ in range(15):
-                # touch g0 (covered by every query count) so each measured
-                # poll recomputes that cohort -- steady-state serving with
-                # live ingest, not a pure cache walk
-                svc.ingest(names[0], churn)
-                svc.flush()
-                t0 = time.time()
-                svc.poll()
-                lats.append(time.time() - t0)
-            lats.sort()
-            tag = f"poll_{'on' if use_planner else 'off'}_{n_queries}q"
-            out[tag] = {
-                "queries": n_queries, "planner": use_planner,
-                "streams": len(names), "groups": groups,
-                "p50_ms": 1e3 * lats[len(lats) // 2],
-                "p95_ms": 1e3 * lats[int(len(lats) * 0.95)],
-            }
-            print(f"{tag:>16}: p50 {out[tag]['p50_ms']:7.2f}ms "
-                  f"p95 {out[tag]['p95_ms']:7.2f}ms")
+            lats.append(time.time() - t0)
+        lats.sort()
+        tag = f"poll_on_{n_queries}q"
+        out[tag] = {
+            "queries": n_queries, "planner": True,
+            "streams": len(names), "groups": groups,
+            "p50_ms": 1e3 * lats[len(lats) // 2],
+            "p95_ms": 1e3 * lats[int(len(lats) * 0.95)],
+        }
+        print(f"{tag:>16}: p50 {out[tag]['p50_ms']:7.2f}ms "
+              f"p95 {out[tag]['p95_ms']:7.2f}ms")
     out["p95_ratio_1000q_vs_10q"] = (out["poll_on_1000q"]["p95_ms"]
                                      / out["poll_on_10q"]["p95_ms"])
     print(f"p95(1000q)/p95(10q), planner on: "

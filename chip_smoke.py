@@ -122,9 +122,7 @@ def _flush_runs_kernel(svc, group: str, n_streams: int) -> bool:
         cfg, est.params, sds((n_streams, L, t, w), jnp.int32),
         sds((n_streams,), jnp.float32), sds((n_streams,), jnp.int32),
         sds((1, n_streams, ROWS, cfg.d), jnp.uint32),
-        sds((1, n_streams, ROWS), jnp.int32), keys,
-        use_pallas=est.use_pallas, interpret=est.interpret,
-        use_fused=est.use_fused, shards=est.shards).compile()
+        sds((1, n_streams, ROWS), jnp.int32), keys).compile()
     return "tpu_custom_call" in compiled.as_text()
 
 
@@ -147,7 +145,7 @@ def phase_service(seed: int, *, tenants: int = SERVICE_TENANTS,
     from repro.data.synthetic import dblp_like
     from repro.kernels import ops, ref
     from repro.service import (ContinuousQuery, EstimationService,
-                               QueryEngine, ServiceConfig, ingest_key)
+                               ServiceConfig, ingest_key, reference_snapshot)
 
     t_setup = time.perf_counter()
     svc = EstimationService(ServiceConfig(batch_rows=ROWS, window_epochs=4))
@@ -232,8 +230,8 @@ def phase_service(seed: int, *, tenants: int = SERVICE_TENANTS,
           "reservoir histograms differ from fused_pairs_ref")
 
     # -- served tables == per-stream numpy oracle (1e-6) ---------------------
-    oracle = QueryEngine(svc.registry, use_fused_query=False).snapshot(
-        sorted({s for q in queries for s in q.streams}))
+    oracle = reference_snapshot(
+        svc.registry, sorted({s for q in queries for s in q.streams}))
     worst = 0.0
     for q in queries:
         res = out[q.name]

@@ -7,7 +7,7 @@ answers 64 streams x every threshold -- the fused batched query engine
 (DESIGN.md §12) stacks all windows into one (N, levels, t, w) tensor and
 runs ONE compiled dispatch (moments, depth medians, the Eq. 4 inversion,
 suffix-sum g_k table, all streams at once).  The per-stream numpy oracle
-(`use_fused_query=False`, the PR 2 path) answers the identical query set
+(`reference_snapshot`) answers the identical query set
 for comparison, and a standing-query poll loop shows the steady-state cost
 with the version-keyed cache: unchanged windows are pure lookups, and one
 flush invalidates exactly the streams whose windows changed.
@@ -17,7 +17,7 @@ import time
 import numpy as np
 
 from repro.core import sjpc
-from repro.service import EstimationService, QueryEngine, ServiceConfig
+from repro.service import EstimationService, ServiceConfig, reference_snapshot
 
 D, S, TENANTS, RECORDS = 6, 4, 64, 2048
 
@@ -33,11 +33,9 @@ svc.flush()
 
 # -- one batched snapshot vs the per-stream reference oracle ---------------
 svc.engine.snapshot().all_thresholds(names[0])   # compile the batched call
-for tag, engine in (("fused batched", svc.engine),
-                    ("per-stream oracle",
-                     QueryEngine(svc.registry, use_fused_query=False))):
-    engine._cache.clear()                        # time compute, not caching
-    snap = engine.snapshot()
+svc.engine._cache.clear()                        # time compute, not caching
+for tag, snap in (("fused batched", svc.engine.snapshot()),
+                  ("per-stream oracle", reference_snapshot(svc.registry))):
     t0 = time.perf_counter()
     tables = {nm: snap.all_thresholds(nm) for nm in names}
     dt = 1e3 * (time.perf_counter() - t0)
@@ -45,8 +43,7 @@ for tag, engine in (("fused batched", svc.engine),
     print(f"{tag:>18}: {cells} (stream, threshold) cells in {dt:7.2f} ms")
 
 fused = svc.engine.snapshot().all_thresholds(names[0])
-oracle = QueryEngine(svc.registry, use_fused_query=False) \
-    .snapshot().all_thresholds(names[0])
+oracle = reference_snapshot(svc.registry).all_thresholds(names[0])
 print(f"\n{names[0]} all-thresholds (fused vs oracle):")
 for k in fused:
     print(f"  g_{k} = {fused[k].estimate:>12.1f} +/- {fused[k].stderr:>10.1f}"

@@ -169,10 +169,8 @@ class IPFEstimator(Estimator):
         n = np.asarray(jax.device_get(states.n), dtype=np.float64)
         return counters, n
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
-        del use_pallas, interpret                  # host-numpy estimator
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
         counters, n = self._host(states)
         y = (counters ** 2).sum(axis=2)            # (N, L) second moments
         N, L = y.shape
@@ -189,10 +187,7 @@ class IPFEstimator(Estimator):
         return self.estimate_batch(stack_states([state]), clamp=clamp)
 
     def estimate_join_batch(self, states_a, states_b, *,
-                            clamp: bool = True,
-                            use_pallas: bool | None = None,
-                            interpret: bool | None = None) -> EstimateTable:
-        del use_pallas, interpret
+                            clamp: bool = True) -> EstimateTable:
         ca, n_a = self._host(states_a)
         cb, n_b = self._host(states_b)
         y = (ca * cb).sum(axis=2)                  # (N, L) inner products
@@ -220,15 +215,14 @@ def _fusion_key(est: IPFEstimator):
     return est.cfg
 
 
-def _factory(cfg, *, params=None, estimator_cfg=None, opts=None):
+def _factory(cfg, *, params=None, estimator_cfg=None):
     """Equal-space factory: spread the group's counter budget
     (L * depth * width int32 cells) across L partitioned rows of
     W = depth * width cells -- memory_bytes == cfg.counters_bytes."""
     del params
-    opts = opts or {}
-    row_width = int(opts.get("row_width", cfg.width * cfg.depth))
     ipf_cfg = estimator_cfg or IPFConfig(
-        d=cfg.d, s=cfg.s, row_width=row_width, seed=cfg.seed ^ 0x1BF0)
+        d=cfg.d, s=cfg.s, row_width=cfg.width * cfg.depth,
+        seed=cfg.seed ^ 0x1BF0)
     return IPFEstimator(ipf_cfg)
 
 

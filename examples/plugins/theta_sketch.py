@@ -214,10 +214,8 @@ class ThetaEstimator(Estimator):
         dup = float((per_key * (per_key - 1.0)).sum()) / theta
         return distinct, dup
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
-        del use_pallas, interpret                 # host-numpy estimator
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
         keys = np.asarray(jax.device_get(states.keys))
         counts = np.asarray(jax.device_get(states.counts))
         tags = np.asarray(jax.device_get(states.tags))
@@ -240,13 +238,11 @@ class ThetaEstimator(Estimator):
         return self.estimate_batch(stack_states([state]), clamp=clamp)
 
 
-def _factory(cfg, *, params=None, estimator_cfg=None, opts=None):
+def _factory(cfg, *, params=None, estimator_cfg=None):
     """Equal-space factory: the sketch budget comes from the group's
     SJPCConfig (DESIGN.md §13), 12 bytes per retained entry."""
     del params
-    opts = opts or {}
-    budget = int(cfg.counters_bytes)
-    capacity = int(opts.get("capacity", max(budget // _ENTRY_BYTES, 8)))
+    capacity = max(int(cfg.counters_bytes) // _ENTRY_BYTES, 8)
     theta_cfg = estimator_cfg or ThetaConfig(
         d=cfg.d, s=cfg.s, capacity=capacity, seed=cfg.seed ^ 0x7E7A)
     return ThetaEstimator(theta_cfg)

@@ -108,18 +108,16 @@ class Estimator:
     def memory_bytes(self) -> int:
         raise NotImplementedError
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
-        """Stacked states (leading N axis) -> the full (N, L) table.
-        ``use_pallas``/``interpret`` are optional dispatch hints for
-        kernel-backed estimators (None = the instance's own default)."""
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
+        """Stacked states (leading N axis) -> the full (N, L) table."""
         raise NotImplementedError
 
     def estimate_ref(self, state, *, clamp: bool = True) -> EstimateTable:
         """Single-state host-numpy reference (N=1 table); the conformance
-        oracle for ``estimate_batch`` and the ``use_fused_query=False``
-        service path.  Default: the batched path on a singleton stack."""
+        oracle for ``estimate_batch`` and the service's
+        ``reference_snapshot``.  Default: the batched path on a singleton
+        stack."""
         return self.estimate_batch(stack_states([state]), clamp=clamp)
 
     # -- generic helpers ----------------------------------------------
@@ -254,7 +252,7 @@ class EstimatorSpec:
     ``stderr_kind``), so legacy ``register(kind, factory)`` calls keep
     working unchanged; :func:`spec_of` performs that resolution.
 
-      factory(sjpc_cfg, *, params=None, estimator_cfg=None, opts=None)
+      factory(sjpc_cfg, *, params=None, estimator_cfg=None)
       fusion(est) -> hashable        planner fusion-signature config part
       exact_oracle(query_kind, records) -> (s -> float)  exact g replay
     """
@@ -363,9 +361,8 @@ def register(kind: str, factory: Callable, *, state_cls: type | None = None,
              exact_oracle: Callable | None = None) -> EstimatorSpec:
     """Register an estimator kind (declaratively, once, for every layer).
 
-    ``factory(sjpc_cfg, params=None, estimator_cfg=None, opts=None)``
-    -> Estimator.  ``estimator_cfg`` overrides the kind's derived config;
-    ``opts`` carries construction kwargs (dispatch flags etc.).  The
+    ``factory(sjpc_cfg, *, params=None, estimator_cfg=None)`` ->
+    Estimator.  ``estimator_cfg`` overrides the kind's derived config.  The
     keyword fields populate the kind's :class:`EstimatorSpec`; omitted
     ones resolve from the instance (see :func:`spec_of`), so the legacy
     two-argument form keeps working.  Identical re-registration is a
@@ -437,16 +434,15 @@ def available() -> list[str]:
     return sorted(k for k, sp in _REGISTRY.items() if sp.factory is not None)
 
 
-def make(kind: str, sjpc_cfg, *, params=None, estimator_cfg=None,
-         opts=None) -> Estimator:
+def make(kind: str, sjpc_cfg, *, params=None,
+         estimator_cfg=None) -> Estimator:
     """Instantiate an estimator for a hash group.
 
     ``sjpc_cfg`` is the group's :class:`~repro.core.sjpc.SJPCConfig`; it
     defines (d, s, seed) for every kind and the byte budget competitors
     match (equal space by construction).  ``params`` carries the group's
     shared hash randomness (SJPC only).  ``estimator_cfg`` overrides the
-    derived per-kind config; ``opts`` carries construction kwargs (the
-    service's dispatch flags).  The service registry caches one instance
+    derived per-kind config.  The service registry caches one instance
     per (group, kind) so a group's streams share one engine and its jit
     caches.
     """
@@ -454,8 +450,7 @@ def make(kind: str, sjpc_cfg, *, params=None, estimator_cfg=None,
     if sp is None or sp.factory is None:
         raise KeyError(
             f"unknown estimator kind {kind!r}; available: {available()}")
-    return sp.factory(sjpc_cfg, params=params,
-                      estimator_cfg=estimator_cfg, opts=opts)
+    return sp.factory(sjpc_cfg, params=params, estimator_cfg=estimator_cfg)
 
 
 def load_plugins(modules=None) -> list[str]:

@@ -348,14 +348,13 @@ class LSHSSEstimator(Estimator):
         g = np.cumsum(x[:, ::-1], axis=1)[:, ::-1] + n[:, None]
         return x, g, y1, same_pairs, cross_pairs
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
         """The stratum scaling of :meth:`_strata`; error bars from the
         stratified bootstrap of DESIGN.md §14 (the bucket totals are
         linear and near-exact; the pair-reservoir fractions carry the
         sampling randomness)."""
-        del clamp, use_pallas, interpret           # pure host-numpy math
+        del clamp                                  # pure host-numpy math
         fields = ("counts", "same_sim", "same_tags", "same_seen",
                   "cross_sim", "cross_tags", "cross_seen", "n", "step")
         with child("query.strata", streams=len(states.n)):
@@ -431,12 +430,11 @@ def derive_config(sjpc_cfg: SJPCConfig, *, num_hash_cols: int = 1) -> LSHSSConfi
                        pair_capacity=pair_capacity, seed=sjpc_cfg.seed)
 
 
-def _factory(sjpc_cfg: SJPCConfig, *, params=None, estimator_cfg=None,
-             opts=None):
+def _factory(sjpc_cfg: SJPCConfig, *, params=None, estimator_cfg=None):
     del params                # no shared hash randomness
     if estimator_cfg is None:
         estimator_cfg = derive_config(sjpc_cfg)
-    return LSHSSEstimator(estimator_cfg, **(dict(opts) if opts else {}))
+    return LSHSSEstimator(estimator_cfg)
 
 
 register("lsh_ss", _factory, state_cls=LSHSSState, linear=False,

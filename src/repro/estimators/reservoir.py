@@ -119,13 +119,9 @@ class ReservoirEstimator(Estimator):
     supports_join = False
 
     def __init__(self, cfg: ReservoirConfig, *,
-                 use_pallas: bool | None = None,
-                 interpret: bool | None = None,
                  bootstrap_replicates: int = uncertainty.DEFAULT_REPLICATES,
                  bootstrap_item_cap: int = uncertainty.DEFAULT_ITEM_CAP):
         self.cfg = cfg
-        self.use_pallas = use_pallas
-        self.interpret = interpret
         # bootstrap error bars (0 replicates disables -> stderr_kind
         # "none"); a capacity-1 reservoir can never hold a pair, so its
         # bars would be identically zero -- disable rather than mislabel
@@ -230,8 +226,8 @@ class ReservoirEstimator(Estimator):
                              stderr_kind=("bootstrap" if self.bootstrap
                                           else "none"))
 
-    def _bootstrap_stderr(self, items, valid, n, step, *, use_pallas,
-                          interpret, pair_fn=None) -> np.ndarray | None:
+    def _bootstrap_stderr(self, items, valid, n, step, *, use_pallas=None,
+                          pair_fn=None) -> np.ndarray | None:
         """(N, L) bootstrap-with-Serfling stderr of the g table, or None
         when disabled (bootstrap_replicates=0)."""
         if not self.bootstrap:
@@ -241,15 +237,12 @@ class ReservoirEstimator(Estimator):
             items, valid, np.asarray(jax.device_get(n), np.float64),
             keys=keys, s=self.s, replicates=self.bootstrap,
             item_cap=self.bootstrap_cap, use_pallas=use_pallas,
-            interpret=interpret, pair_fn=pair_fn)
+            pair_fn=pair_fn)
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
         del clamp                                  # counts are >= 0 already
         from repro.kernels.ops import fused_pairs
-        use_pallas = self.use_pallas if use_pallas is None else use_pallas
-        interpret = self.interpret if interpret is None else interpret
         # device arrays flow straight into the kernel (no host round-trip
         # re-uploading the sample per query); only the small outputs --
         # histogram, valid counts, n -- are fetched
@@ -257,13 +250,11 @@ class ReservoirEstimator(Estimator):
         N, R = valid.shape
         with child("query.pairs", streams=N, slots=R):
             hist = np.asarray(jax.device_get(fused_pairs(
-                states.items, valid, use_pallas=use_pallas,
-                interpret=interpret))).astype(np.float64)
+                states.items, valid))).astype(np.float64)
         n = np.asarray(jax.device_get(states.n), np.float64)
         m = np.asarray(jax.device_get(valid.sum(axis=1)), np.float64)
         stderr = self._bootstrap_stderr(states.items, valid, states.n,
-                                        states.step, use_pallas=use_pallas,
-                                        interpret=interpret)
+                                        states.step)
         return self._table(hist, n, m, stderr)
 
     def estimate_ref(self, state: ReservoirState, *,
@@ -293,7 +284,7 @@ class ReservoirEstimator(Estimator):
         stderr = self._bootstrap_stderr(
             items[None], valid[None], jnp.asarray(state.n)[None],
             jnp.asarray(state.step)[None], use_pallas=False,
-            interpret=None, pair_fn=pair_fn)
+            pair_fn=pair_fn)
         return self._table(hist[None], n,
                            np.array([float(valid.sum())], np.float64),
                            stderr)
@@ -305,14 +296,13 @@ def capacity_for_bytes(sjpc_cfg: SJPCConfig) -> int:
     return max(1, sjpc_cfg.counters_bytes // ((sjpc_cfg.d + 1) * 4))
 
 
-def _factory(sjpc_cfg: SJPCConfig, *, params=None, estimator_cfg=None,
-             opts=None):
+def _factory(sjpc_cfg: SJPCConfig, *, params=None, estimator_cfg=None):
     del params                               # no shared hash randomness
     if estimator_cfg is None:
         estimator_cfg = ReservoirConfig(
             d=sjpc_cfg.d, s=sjpc_cfg.s, capacity=capacity_for_bytes(sjpc_cfg),
             seed=sjpc_cfg.seed)
-    return ReservoirEstimator(estimator_cfg, **(dict(opts) if opts else {}))
+    return ReservoirEstimator(estimator_cfg)
 
 
 register("reservoir", _factory, state_cls=ReservoirState, linear=False,

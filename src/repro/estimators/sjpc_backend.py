@@ -1,13 +1,12 @@
 """SJPC behind the Estimator protocol: a thin adapter over core/sjpc.py.
 
 Nothing numerical lives here -- every path delegates to the PR 1-3 code
-(``sjpc.update_fused`` / ``ShardedIngest`` semantics via the service's
-``multi_round_update`` scan, ``sjpc.estimate_batch``, the Theorem 1/2
-bounds), so the fused ingest/query conformance suites keep pinning the
-exact same functions.  The adapter's job is shape only: expose those
-functions with the protocol signatures the generalized service layers
-(registry/window/ingest/query) dispatch over, alongside the reservoir and
-LSH-SS competitors.
+(``sjpc.update_fused`` via the service's ``multi_round_update`` scan,
+``sjpc.estimate_batch``, the Theorem 1/2 bounds), so the fused
+ingest/query conformance suites keep pinning the exact same functions.
+The adapter's job is shape only: expose those functions with the protocol
+signatures the generalized service layers (registry/window/ingest/query)
+dispatch over, alongside the reservoir and LSH-SS competitors.
 """
 from __future__ import annotations
 
@@ -29,15 +28,9 @@ class SJPCEstimator(Estimator):
     linear = True
     supports_join = True
 
-    def __init__(self, cfg: SJPCConfig, params: SJPCParams | None = None, *,
-                 use_fused: bool = True, use_pallas: bool | None = None,
-                 interpret: bool | None = None, shards: int = 1):
+    def __init__(self, cfg: SJPCConfig, params: SJPCParams | None = None):
         self.cfg = cfg
         self.params = params if params is not None else sjpc.init(cfg)[0]
-        self.use_fused = use_fused
-        self.use_pallas = use_pallas
-        self.interpret = interpret
-        self.shards = shards
 
     # -- static properties --------------------------------------------
     @property
@@ -67,9 +60,7 @@ class SJPCEstimator(Estimator):
         from repro.service.ingest import multi_round_update
         counters, n, steps = multi_round_update(
             self.cfg, self.params, states.counters, states.n, states.step,
-            values, row_mask, keys, use_pallas=self.use_pallas,
-            interpret=self.interpret, use_fused=self.use_fused,
-            shards=self.shards)
+            values, row_mask, keys)
         return SJPCState(counters=counters, n=n, step=steps)
 
     def merge(self, a: SJPCState, b: SJPCState) -> SJPCState:
@@ -78,13 +69,10 @@ class SJPCEstimator(Estimator):
     def subtract(self, a: SJPCState, b: SJPCState) -> SJPCState:
         return sjpc.subtract(a, b)
 
-    def estimate_batch(self, states, *, clamp: bool = True,
-                       use_pallas: bool | None = None,
-                       interpret: bool | None = None) -> EstimateTable:
-        be = sjpc.estimate_batch(
-            self.cfg, states.counters, states.n, clamp=clamp,
-            use_pallas=self.use_pallas if use_pallas is None else use_pallas,
-            interpret=self.interpret if interpret is None else interpret)
+    def estimate_batch(self, states, *,
+                       clamp: bool = True) -> EstimateTable:
+        be = sjpc.estimate_batch(self.cfg, states.counters, states.n,
+                                 clamp=clamp)
         return EstimateTable(*be, stderr_kind="analytic")
 
     def estimate_ref(self, state: SJPCState, *,
@@ -112,14 +100,11 @@ class SJPCEstimator(Estimator):
                              stderr_kind="analytic")
 
     # -- join (SJPC-only capability) ----------------------------------
-    def estimate_join_batch(self, states_a, states_b, *, clamp: bool = True,
-                            use_pallas: bool | None = None,
-                            interpret: bool | None = None) -> EstimateTable:
+    def estimate_join_batch(self, states_a, states_b, *,
+                            clamp: bool = True) -> EstimateTable:
         be = sjpc.estimate_join_batch(
             self.cfg, states_a.counters, states_b.counters,
-            states_a.n, states_b.n, clamp=clamp,
-            use_pallas=self.use_pallas if use_pallas is None else use_pallas,
-            interpret=self.interpret if interpret is None else interpret)
+            states_a.n, states_b.n, clamp=clamp)
         return EstimateTable(*be, stderr_kind="analytic")
 
     def estimate_join_ref(self, state_a, state_b, *,
@@ -148,12 +133,12 @@ class SJPCEstimator(Estimator):
                              stderr_kind="analytic")
 
 
-def _factory(sjpc_cfg, *, params=None, estimator_cfg=None, opts=None):
-    # SJPC has no separate config (it IS the group's SJPCConfig); both
-    # channels carry construction kwargs, explicit estimator_cfg winning
-    kwargs = {**(dict(opts) if opts else {}),
-              **(dict(estimator_cfg) if estimator_cfg else {})}
-    return SJPCEstimator(sjpc_cfg, params, **kwargs)
+def _factory(sjpc_cfg, *, params=None, estimator_cfg=None):
+    # SJPC has no separate config: it IS the group's SJPCConfig
+    if estimator_cfg is not None:
+        raise ValueError("sjpc takes no estimator_cfg; its config is the "
+                         "hash group's SJPCConfig")
+    return SJPCEstimator(sjpc_cfg, params)
 
 
 register("sjpc", _factory, state_cls=SJPCState, linear=True,

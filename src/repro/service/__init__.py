@@ -6,9 +6,10 @@ bars.  See DESIGN.md §10 for the architecture and invariants.
 """
 from .client import MonitorServiceClient
 from .ingest import (IngestPipeline, ingest_key, ingest_key_grid,
-                     multi_round_update, multi_stream_update)
+                     multi_round_update)
 from .planner import PlannerConfig, QueryPlanner
-from .query import ContinuousQuery, QueryEngine, QueryResult, Snapshot
+from .query import (ContinuousQuery, QueryEngine, QueryResult, Snapshot,
+                    reference_snapshot)
 from .registry import HashGroup, StreamEntry, StreamRegistry
 from .service import EstimationService, ServiceConfig
 from .window import WindowedSketch
@@ -18,5 +19,5 @@ __all__ = [
     "MonitorServiceClient", "PlannerConfig", "QueryEngine", "QueryPlanner",
     "QueryResult", "ServiceConfig", "Snapshot", "StreamEntry",
     "StreamRegistry", "WindowedSketch", "ingest_key", "ingest_key_grid",
-    "multi_round_update", "multi_stream_update",
+    "multi_round_update", "reference_snapshot",
 ]

@@ -10,22 +10,11 @@ coalesced rounds of a flush in one ``Estimator.ingest_rounds`` dispatch
 per **estimator cohort** (streams of one kind; DESIGN.md §13.4).  A group
 whose streams all run SJPC -- the default -- is exactly one ``lax.scan``
 inside one jit (:func:`multi_round_update`), vmapping the single-stream
-update over the stream axis, bit-identical to the pre-protocol pipeline.  The inner update is the **fused** ingest path by default
-(``sjpc.update_fused``: fingerprint -> multi-level sketch in one kernel
-launch on TPU, the fused-scatter formulation elsewhere); the original
-per-level ``sjpc.update`` stays available behind ``use_fused=False`` as the
-conformance oracle -- both produce bit-identical counters for the same keys
-(tests/test_fused_ingest.py, tests/test_service.py).
-
-Sharding: with ``shards > 1`` every round's per-stream rows are split across
-a leading shard axis and folded into shard-local *delta* sketches inside the
-scan -- no cross-shard reduction per round.  The deltas merge once per flush
-after the scan (``sjpc.merge`` semantics: counters add, steps sum), so R
-micro-batch rounds cost ONE cross-device reduction (merge deferral).  Arrays
-carrying the shard axis may be laid out across a device mesh; the shard-axis
-``sum`` is then the deferred ``psum``.  Per-shard keys are
-``fold_in(round_key, shard)``; ``shards=1`` (the default) uses the round key
-directly and is bit-compatible with the PR 1 single-device pipeline.
+update over the stream axis.  The inner update is ``sjpc.update_fused``
+(fingerprint -> multi-level sketch in one kernel launch on TPU, the
+fused-scatter formulation elsewhere), bit-identical to the per-level
+reference ``sjpc.update`` for the same keys (tests/test_fused_ingest.py,
+tests/test_service.py replay streams through it).
 
 Shapes are static: records are coalesced into rounds of exactly
 ``batch_rows`` rows per stream, the tail round padded with zero rows that
@@ -58,9 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sjpc
-from repro.core.sjpc import SJPCConfig, SJPCParams, SJPCState
+from repro.core.sjpc import SJPCConfig, SJPCState
 from repro.estimators import index_state, stack_states
-from repro.kernels.ops import make_sjpc_update_fn
 from repro.obs import Observability
 
 from .registry import HashGroup, StreamEntry
@@ -88,84 +76,31 @@ def ingest_key_grid(seed, uids, round_idx) -> jax.Array:
         jnp.broadcast_to(uids[None, :], round_idx.shape), round_idx)
 
 
-def _one_stream(cfg, params, use_fused, use_pallas, interpret,
-                c, n_s, step_s, vals, mask, key):
-    st = SJPCState(c, n_s, step_s)
-    if use_fused:
-        st = sjpc.update_fused(cfg, params, st, vals, key=key, row_mask=mask,
-                               use_pallas=use_pallas, interpret=interpret)
-    else:
-        st = sjpc.update(cfg, params, st, vals, key=key, row_mask=mask,
-                         update_fn=make_sjpc_update_fn(use_pallas=use_pallas,
-                                                       interpret=interpret))
+def _one_stream(cfg, params, c, n_s, step_s, vals, mask, key):
+    st = sjpc.update_fused(cfg, params, SJPCState(c, n_s, step_s), vals,
+                           key=key, row_mask=mask)
     return st.counters, st.n, st.step
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "interpret",
-                                             "use_fused"))
-def multi_stream_update(cfg, params, counters, n, steps, values, row_mask,
-                        keys, *, use_pallas=None, interpret=None,
-                        use_fused=False):
-    """One device dispatch updating every stream of a group (single round).
-
-    counters (S, L, t, w) int32; n (S,) f32; steps (S,) int32;
-    values (S, B, d) uint32; row_mask (S, B) int32; keys (S,) PRNG keys.
-    Returns the updated (counters, n, steps).
-    """
-    one = functools.partial(_one_stream, cfg, params, use_fused, use_pallas,
-                            interpret)
-    return jax.vmap(one)(counters, n, steps, values, row_mask, keys)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "use_pallas", "interpret",
-                                             "use_fused", "shards"))
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def multi_round_update(cfg, params, counters, n, steps, values, row_mask,
-                       keys, *, use_pallas=None, interpret=None,
-                       use_fused=True, shards=1):
+                       keys):
     """ALL rounds of a flush in one dispatch: ``lax.scan`` over the round
-    axis of values (R, S, B, d) / row_mask (R, S, B) / keys (R, S).
+    axis of values (R, S, B, d) / row_mask (R, S, B) / keys (R, S), each
+    round one vmap of the single-stream update over the stream axis.
 
-    With ``shards > 1`` each round splits its B rows into ``shards`` slices
-    folded into shard-local delta sketches (keys ``fold_in(key, shard)``);
-    the single cross-shard merge happens after the scan -- R rounds, one
-    reduction.  Requires B % shards == 0 (the pipeline enforces it).
+    counters (S, L, t, w) int32; n (S,) f32; steps (S,) int32.  Returns
+    the updated (counters, n, steps).
     """
-    one = functools.partial(_one_stream, cfg, params, use_fused, use_pallas,
-                            interpret)
-
-    if shards == 1:
-        def body(carry, rnd):
-            vals, mask, ks = rnd
-            return jax.vmap(one)(*carry, vals, mask, ks), None
-
-        carry, _ = jax.lax.scan(body, (counters, n, steps),
-                                (values, row_mask, keys))
-        return carry
-
-    R, S, B, d = values.shape
-    assert B % shards == 0
-    per = B // shards
-    # (R, S, B, ...) -> (R, shards, S, per, ...): shard-major so the scan
-    # body vmaps (shards, S) and the shard axis can live on a device mesh.
-    vals_sh = values.reshape(R, S, shards, per, d).swapaxes(1, 2)
-    mask_sh = row_mask.reshape(R, S, shards, per).swapaxes(1, 2)
-    fold = jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(0, None)),
-                    in_axes=(0, None))
-    keys_sh = jnp.stack([fold(keys, j) for j in range(shards)], axis=1)
-
-    zeros = (jnp.zeros((shards,) + counters.shape, counters.dtype),
-             jnp.zeros((shards,) + n.shape, n.dtype),
-             jnp.zeros((shards,) + steps.shape, steps.dtype))
+    one = jax.vmap(functools.partial(_one_stream, cfg, params))
 
     def body(carry, rnd):
         vals, mask, ks = rnd
-        return jax.vmap(jax.vmap(one))(*carry, vals, mask, ks), None
+        return one(*carry, vals, mask, ks), None
 
-    (dc, dn, dstep), _ = jax.lax.scan(body, zeros,
-                                      (vals_sh, mask_sh, keys_sh))
-    # the deferred merge: ONE reduction over the shard axis for all R rounds
-    return (counters + dc.sum(axis=0), n + dn.sum(axis=0),
-            steps + dstep.sum(axis=0))
+    carry, _ = jax.lax.scan(body, (counters, n, steps),
+                            (values, row_mask, keys))
+    return carry
 
 
 class IngestPipeline:
@@ -174,18 +109,10 @@ class IngestPipeline:
     fixed-shape coalescing, not about lock-free concurrency)."""
 
     def __init__(self, group: HashGroup, *, batch_rows: int = 256,
-                 use_pallas: bool | None = None, interpret: bool | None = None,
-                 use_fused: bool = True, shards: int = 1,
                  obs: Observability | None = None):
-        assert batch_rows >= 1 and shards >= 1
-        assert batch_rows % shards == 0, \
-            f"batch_rows={batch_rows} must be divisible by shards={shards}"
+        assert batch_rows >= 1
         self.group = group
         self.batch_rows = batch_rows
-        self.use_pallas = use_pallas
-        self.interpret = interpret
-        self.use_fused = use_fused
-        self.shards = shards
         self.obs = obs if obs is not None else Observability.disabled()
         self._front: dict[str, list[np.ndarray]] = {}
         self._front_rows = 0                 # queue depth, kept incrementally

@@ -324,51 +324,50 @@ class QueryPlanner:
         throttled = self._admit(queries)
         plan = self._plan_for(snap, queries)
         m = self.obs.metrics
-        if snap._use_fused:
-            # priority of each cohort/pair = most critical admitted query
-            # needing it; untouched launches are skipped entirely
-            cohort_prio: dict = {}
-            pair_prio: dict = {}
-            for name, q in queries.items():
-                if name in throttled:
-                    continue
-                if q.kind == "join":
-                    pair = plan.query_pair[name]
-                    pair_prio[pair] = min(pair_prio.get(pair, q.priority),
-                                          q.priority)
-                else:
-                    ck = plan.query_cohort[name]
-                    cohort_prio[ck] = min(cohort_prio.get(ck, q.priority),
-                                          q.priority)
-            fresh = self._apply_coalescing(snap, cohort_prio, pair_prio)
-            launches = [(min(cohort_prio[ck] for ck in cks), "self", cks)
-                        for cks in plan.self_launches
-                        if any(ck in cohort_prio for ck in cks)]
-            launches += [(min(pair_prio[p] for p in ps), "join", ps)
-                         for ps in plan.join_launches
-                         if any(p in pair_prio for p in ps)]
-            launches.sort(key=lambda t: t[0])
-            for _, op, members in launches:
-                if op == "self":
-                    done = snap.fused_self_batch(
-                        [snap._cohort_views(*ck) for ck in members
-                         if ck in cohort_prio])
-                    if done and m.enabled:
-                        m.inc("planner_fused_launches_total", op="self")
+        # priority of each cohort/pair = most critical admitted query
+        # needing it; untouched launches are skipped entirely
+        cohort_prio: dict = {}
+        pair_prio: dict = {}
+        for name, q in queries.items():
+            if name in throttled:
+                continue
+            if q.kind == "join":
+                pair = plan.query_pair[name]
+                pair_prio[pair] = min(pair_prio.get(pair, q.priority),
+                                      q.priority)
+            else:
+                ck = plan.query_cohort[name]
+                cohort_prio[ck] = min(cohort_prio.get(ck, q.priority),
+                                      q.priority)
+        fresh = self._apply_coalescing(snap, cohort_prio, pair_prio)
+        launches = [(min(cohort_prio[ck] for ck in cks), "self", cks)
+                    for cks in plan.self_launches
+                    if any(ck in cohort_prio for ck in cks)]
+        launches += [(min(pair_prio[p] for p in ps), "join", ps)
+                     for ps in plan.join_launches
+                     if any(p in pair_prio for p in ps)]
+        launches.sort(key=lambda t: t[0])
+        for _, op, members in launches:
+            if op == "self":
+                done = snap.fused_self_batch(
+                    [snap._cohort_views(*ck) for ck in members
+                     if ck in cohort_prio])
+                if done and m.enabled:
+                    m.inc("planner_fused_launches_total", op="self")
+                    m.inc("planner_fused_cohorts_total",
+                          value=float(done), op="self")
+            else:
+                pairs = [p for p in members if p in pair_prio
+                         and ("join", p[0], snap._view(p[0]).version,
+                              p[1], snap._view(p[1]).version, True)
+                         not in snap._cache]
+                if pairs:
+                    if m.enabled:
+                        m.inc("planner_fused_launches_total", op="join")
                         m.inc("planner_fused_cohorts_total",
-                              value=float(done), op="self")
-                else:
-                    pairs = [p for p in members if p in pair_prio
-                             and ("join", p[0], snap._view(p[0]).version,
-                                  p[1], snap._view(p[1]).version, True)
-                             not in snap._cache]
-                    if pairs:
-                        if m.enabled:
-                            m.inc("planner_fused_launches_total", op="join")
-                            m.inc("planner_fused_cohorts_total",
-                                  value=float(len(pairs)), op="join")
-                        snap._join_batch(pairs, True)
-            self._stamp_coalescing(fresh)
+                              value=float(len(pairs)), op="join")
+                    snap._join_batch(pairs, True)
+        self._stamp_coalescing(fresh)
         out = {}
         for name, q in queries.items():
             if name in throttled:

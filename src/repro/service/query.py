@@ -10,11 +10,12 @@ tensor and ``sjpc.estimate_batch`` answers every (stream, threshold) cell
 -- level moments, depth medians, the Eq. 4 inversion, and the suffix-sum
 g_k table -- from ONE compiled call (a Pallas launch on TPU, the fused jnp
 reduction elsewhere).  Join queries batch the same way through
-``sjpc.estimate_join_batch``; ``Snapshot.prefetch`` lets ``service.poll``
-answer every registered join pair of a group in one additional call.  The
-PR 1 per-stream numpy path (int64-exact F2 + float64 inversion per stream)
-is kept verbatim behind ``use_fused_query=False`` as the conformance
-oracle; tests/test_fused_query.py holds the two within 1e-6.
+``sjpc.estimate_join_batch``; the planner (``service.poll``) answers every
+registered join pair of a bucket in one call.  :func:`reference_snapshot`
+answers the same queries through each estimator's per-stream host oracle
+(SJPC: int64-exact F2 + float64 inversion per stream); it is a plain
+function for conformance tests, and tests/test_fused_query.py holds the two
+within 1e-6.
 
 The stack behind each self launch stays **resident** across polls
 (DESIGN.md §12.1): the engine keeps, per launch, the stacked states and
@@ -57,7 +58,7 @@ from repro.core.sjpc import SJPCConfig
 from repro.estimators import Estimator, stack_states
 from repro.obs import Observability
 
-from .registry import StreamRegistry
+from .registry import StreamEntry, StreamRegistry
 
 _CACHE_MAX_ENTRIES = 4096      # shared-cache bound; LRU-evicted beyond
 
@@ -131,17 +132,11 @@ class Snapshot:
 
     def __init__(self, views: dict[str, _StreamView],
                  registry: StreamRegistry, *,
-                 use_fused_query: bool = True,
-                 use_pallas: bool | None = None,
-                 interpret: bool | None = None,
                  cache: dict | None = None,
                  stacks: dict | None = None,
                  obs: Observability | None = None):
         self._views = views
         self._registry = registry
-        self._use_fused = use_fused_query
-        self._use_pallas = use_pallas
-        self._interpret = interpret
         self._cache = {} if cache is None else cache
         self._stacks = stacks      # resident self-launch stacks, or None
         self._local: dict = {}     # per-snapshot memo of shared-cache hits
@@ -208,9 +203,7 @@ class Snapshot:
                                 streams=len(views)) as sp:
                 states = self._stacked((self._cohort_key(views),), views,
                                        group_id)
-                est = views[0].estimator.estimate_batch(
-                    states, clamp=clamp, use_pallas=self._use_pallas,
-                    interpret=self._interpret)
+                est = views[0].estimator.estimate_batch(states, clamp=clamp)
                 sp.sync(*jax.tree_util.tree_leaves(est))
             self._cache[key] = ({v.name: i for i, v in enumerate(views)}, est)
         self._local[local_key] = self._cache_get(key)
@@ -306,9 +299,7 @@ class Snapshot:
             states = self._stacked(
                 tuple(self._cohort_key(c) for c in todo), views,
                 "+".join(gids))
-            est = views[0].estimator.estimate_batch(
-                states, clamp=clamp, use_pallas=self._use_pallas,
-                interpret=self._interpret)
+            est = views[0].estimator.estimate_batch(states, clamp=clamp)
             sp.sync(*jax.tree_util.tree_leaves(est))
         lo = 0
         for c in todo:
@@ -323,7 +314,7 @@ class Snapshot:
 
     def _join_batch(self, pairs: list[tuple[str, str]], clamp: bool) -> None:
         """Answer many join pairs of one group in a single compiled call,
-        filling the per-pair cache entries ``prefetch``/``join`` read."""
+        filling the per-pair cache entries ``join`` reads."""
         views_a = [self._view(a) for a, _ in pairs]
         views_b = [self._view(b) for _, b in pairs]
         gid, kind = views_a[0].group_id, views_a[0].kind
@@ -336,8 +327,7 @@ class Snapshot:
             with self._obs.span("query.stack", streams=len(views_b)):
                 states_b = stack_states([v.state for v in views_b])
             est = views_a[0].estimator.estimate_join_batch(
-                states_a, states_b, clamp=clamp,
-                use_pallas=self._use_pallas, interpret=self._interpret)
+                states_a, states_b, clamp=clamp)
             sp.sync(*jax.tree_util.tree_leaves(est))
         for i, (va, vb) in enumerate(zip(views_a, views_b)):
             k = ("join", va.name, va.version, vb.name, vb.version, clamp)
@@ -346,50 +336,19 @@ class Snapshot:
             self._cache[k] = type(est)(*(a[i:i + 1] if isinstance(
                 a, (np.ndarray, jax.Array)) else a for a in est))
 
-    def prefetch(self, queries, *, clamp: bool = True) -> None:
-        """Warm the cache for a batch of :class:`ContinuousQuery` -- one
-        ``estimate_batch`` per touched group plus one ``estimate_join_batch``
-        per group with join pairs (instead of one call per query)."""
-        if not self._use_fused:
-            return
-        m = self._obs.metrics
-        if m.enabled and queries:
-            m.inc("query_prefetch_queries_total", value=float(len(queries)))
-        # join pairs bucket like the self path splits cohorts: by estimator
-        # INSTANCE and state shapes, not group alone -- a group mixing
-        # estimator_cfg-overridden streams or backing-epoch geometries must
-        # not stack mismatched states into one estimate_join_batch launch
-        join_pairs: dict[tuple, list[tuple[str, str]]] = {}
-        for q in queries:
-            if q.kind == "join":
-                a, b = q.streams
-                self._registry.require_joinable(a, b)
-                va, vb = self._view(a), self._view(b)
-                k = ("join", a, va.version, b, vb.version, clamp)
-                if k not in self._cache:
-                    bucket = (va.group_id, id(va.estimator),
-                              id(vb.estimator), va.shape_sig, vb.shape_sig)
-                    join_pairs.setdefault(bucket, []).append((a, b))
-            else:
-                self._self_batch(self._view(q.streams[0]), clamp)
-        for bucket, pairs in join_pairs.items():
-            pairs = sorted(set(pairs))
-            if m.enabled:
-                m.inc("query_prefetch_join_pairs_total",
-                      value=float(len(pairs)), group=bucket[0])
-            self._join_batch(pairs, clamp)
+    def _self_table(self, v: _StreamView, clamp: bool):
+        """(table, row) answering ``v``'s self-join cells."""
+        index, est = self._self_batch(v, clamp)
+        return est, index[v.name]
 
-    # -- per-stream reference oracle -----------------------------------
-    def _ref_table(self, name: str, clamp: bool):
-        """The estimator's per-stream host oracle (SJPC: int64-exact F2 +
-        float64 inversion -- the PR 1 path), memoized by window version."""
-        v = self._view(name)
-        key = ("ref", name, v.version, clamp)
-        hit = key in self._cache
-        self._count_cache(hit, v.group_id, v.kind, "ref")
+    def _join_table(self, va: _StreamView, vb: _StreamView, clamp: bool):
+        """The one-row table answering the pair's join cells."""
+        k = ("join", va.name, va.version, vb.name, vb.version, clamp)
+        hit = k in self._cache
+        self._count_cache(hit, va.group_id, va.kind, "join")
         if not hit:
-            self._cache[key] = v.estimator.estimate_ref(v.state, clamp=clamp)
-        return self._cache_get(key)
+            self._join_batch([(va.name, vb.name)], clamp)
+        return self._cache_get(k)
 
     # ------------------------------------------------------------------
     def self_join(self, name: str, s: int | None = None, *,
@@ -401,12 +360,7 @@ class Snapshot:
             raise ValueError(f"s={s} outside sketched range "
                              f"[{v.cfg.s}, {v.cfg.d}] of {name!r}")
         li = s - v.cfg.s
-        if self._use_fused:
-            index, est = self._self_batch(v, clamp)
-            i = index[name]
-        else:
-            est = self._ref_table(name, clamp)
-            i = 0
+        est, i = self._self_table(v, clamp)
         g = float(est.g[i, li])
         on, off = float(est.stderr[i, li]), float(est.stderr_offline[i, li])
         xs = est.x[i, li:]
@@ -423,21 +377,7 @@ class Snapshot:
         if not cfg.s <= s <= cfg.d:
             raise ValueError(f"s={s} outside sketched range [{cfg.s}, {cfg.d}]")
         li = s - cfg.s
-        if self._use_fused:
-            k = ("join", a, va.version, b, vb.version, clamp)
-            hit = k in self._cache
-            self._count_cache(hit, va.group_id, va.kind, "join")
-            if not hit:
-                self._join_batch([(a, b)], clamp)
-            est = self._cache_get(k)
-        else:
-            k = ("join_ref", a, va.version, b, vb.version, clamp)
-            hit = k in self._cache
-            self._count_cache(hit, va.group_id, va.kind, "join")
-            if not hit:
-                self._cache[k] = va.estimator.estimate_join_ref(
-                    va.state, vb.state, clamp=clamp)
-            est = self._cache_get(k)
+        est = self._join_table(va, vb, clamp)
         j = float(est.g[0, li])
         on, off = float(est.stderr[0, li]), float(est.stderr_offline[0, li])
         xs = est.x[0, li:]
@@ -453,6 +393,47 @@ class Snapshot:
 
     def streams(self) -> list[str]:
         return list(self._views)
+
+
+class _ReferenceSnapshot(Snapshot):
+    """:class:`Snapshot`'s queries answered by each estimator's per-stream
+    host oracle (``estimate_ref`` / ``estimate_join_ref``): no cache, no
+    stacking, no batched launch."""
+
+    def _self_table(self, v: _StreamView, clamp: bool):
+        return v.estimator.estimate_ref(v.state, clamp=clamp), 0
+
+    def _join_table(self, va: _StreamView, vb: _StreamView, clamp: bool):
+        return va.estimator.estimate_join_ref(va.state, vb.state, clamp=clamp)
+
+
+def _stream_view(registry: StreamRegistry, e: StreamEntry) -> _StreamView:
+    """``e``'s windowed state and coverage metadata at this instant."""
+    st = e.window.window_state()
+    return _StreamView(
+        name=e.name, cfg=registry.group(e.group_id).cfg, state=st,
+        estimator=e.estimator, kind=e.estimator_kind, n=e.window.n_live(),
+        live_epochs=e.window.live_epochs,
+        window_epochs=e.window.window_epochs, group_id=e.group_id,
+        version=e.window.version,
+        shape_sig=tuple(tuple(np.shape(leaf)) for leaf in
+                        jax.tree_util.tree_leaves(st)))
+
+
+def _entries(registry: StreamRegistry, names: list[str] | None) -> list:
+    return (registry.streams() if names is None
+            else [registry.stream(n) for n in names])
+
+
+def reference_snapshot(registry: StreamRegistry,
+                       names: list[str] | None = None) -> Snapshot:
+    """The conformance oracle for :meth:`QueryEngine.snapshot`: the same
+    ``self_join`` / ``join`` / ``all_thresholds`` surface, each answer
+    computed afresh from the stream's ``window.window_state()`` by the
+    estimator's reference (SJPC: int64-exact F2 + float64 inversion)."""
+    return _ReferenceSnapshot(
+        {e.name: _stream_view(registry, e) for e in _entries(registry, names)},
+        registry)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -483,15 +464,9 @@ class ContinuousQuery:
 
 class QueryEngine:
     def __init__(self, registry: StreamRegistry, *,
-                 use_fused_query: bool = True,
-                 use_pallas: bool | None = None,
-                 interpret: bool | None = None,
                  cache_max_entries: int | None = None,
                  obs: Observability | None = None):
         self._registry = registry
-        self.use_fused_query = use_fused_query
-        self.use_pallas = use_pallas
-        self.interpret = interpret
         self._cache: collections.OrderedDict = collections.OrderedDict()
         self._cache_max = (_CACHE_MAX_ENTRIES if cache_max_entries is None
                            else cache_max_entries)
@@ -500,8 +475,7 @@ class QueryEngine:
         self.obs = obs if obs is not None else Observability.disabled()
 
     def snapshot(self, names: list[str] | None = None) -> Snapshot:
-        entries = (self._registry.streams() if names is None
-                   else [self._registry.stream(n) for n in names])
+        entries = _entries(self._registry, names)
         # LRU eviction: drop only the least-recently-used entries down to
         # the bound (every read refreshes recency via Snapshot._cache_get),
         # so one overflowing snapshot can never cold-start hot standing
@@ -516,23 +490,10 @@ class QueryEngine:
         with self.obs.span("query.snapshot",
                            histogram="query_snapshot_seconds",
                            streams=len(entries)):
-            views = {}
-            for e in entries:
-                st = e.window.window_state()
-                views[e.name] = _StreamView(
-                    name=e.name, cfg=self._registry.group(e.group_id).cfg,
-                    state=st, estimator=e.estimator, kind=e.estimator_kind,
-                    n=e.window.n_live(),
-                    live_epochs=e.window.live_epochs,
-                    window_epochs=e.window.window_epochs,
-                    group_id=e.group_id, version=e.window.version,
-                    shape_sig=tuple(tuple(np.shape(leaf)) for leaf in
-                                    jax.tree_util.tree_leaves(st)))
+            views = {e.name: _stream_view(self._registry, e)
+                     for e in entries}
         if self.obs.metrics.enabled:
             self.obs.metrics.set("query_cache_entries", float(len(self._cache)))
-        return Snapshot(views, self._registry,
-                        use_fused_query=self.use_fused_query,
-                        use_pallas=self.use_pallas, interpret=self.interpret,
-                        cache=self._cache,
+        return Snapshot(views, self._registry, cache=self._cache,
                         stacks=self._stacks if names is None else None,
                         obs=self.obs)

@@ -38,27 +38,20 @@ class HashGroup:
     group_id: str
     cfg: SJPCConfig
     params: SJPCParams
-    # per-kind construction overrides (e.g. the service's fused/pallas
-    # flags for "sjpc") and the per-kind instance cache
-    estimator_opts: dict = dataclasses.field(
-        default_factory=dict, repr=False, compare=False)
+    # the per-kind instance cache
     _estimators: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
     def estimator(self, kind: str = "sjpc",
                   estimator_cfg=None) -> Estimator:
         """The group's shared estimator instance for ``kind`` (constructed
-        on first use; an explicit ``estimator_cfg`` bypasses the cache).
-        ``estimator_opts[kind]`` (the service's dispatch flags) apply
-        either way."""
+        on first use; an explicit ``estimator_cfg`` bypasses the cache)."""
         if estimator_cfg is not None:
             return est_mod.make(kind, self.cfg, params=self.params,
-                                estimator_cfg=estimator_cfg,
-                                opts=self.estimator_opts.get(kind))
+                                estimator_cfg=estimator_cfg)
         if kind not in self._estimators:
-            self._estimators[kind] = est_mod.make(
-                kind, self.cfg, params=self.params,
-                opts=self.estimator_opts.get(kind))
+            self._estimators[kind] = est_mod.make(kind, self.cfg,
+                                                  params=self.params)
         return self._estimators[kind]
 
     def cached_estimator(self, kind: str) -> Estimator | None:
@@ -99,13 +92,11 @@ class StreamRegistry:
         self.version = 0
 
     # ------------------------------------------------------------------
-    def create_group(self, group_id: str, cfg: SJPCConfig, *,
-                     estimator_opts: dict | None = None) -> HashGroup:
+    def create_group(self, group_id: str, cfg: SJPCConfig) -> HashGroup:
         if group_id in self._groups:
             raise ValueError(f"group {group_id!r} already exists")
         params, _ = sjpc.init(cfg)
-        group = HashGroup(group_id=group_id, cfg=cfg, params=params,
-                          estimator_opts=dict(estimator_opts or {}))
+        group = HashGroup(group_id=group_id, cfg=cfg, params=params)
         self._groups[group_id] = group
         self.version += 1
         return group

@@ -6,6 +6,7 @@ Composes the subsystem (DESIGN.md §10):
   window.py     per-stream sliding windows; expiry = counter subtraction
   ingest.py     double-buffered, fixed-shape, single-dispatch batched ingest
   query.py      snapshot-based queries with analytical error bars
+  planner.py    poll(): fused launches, priorities, tenant admission
 
 Lifecycle:
 
@@ -20,7 +21,10 @@ Lifecycle:
 ``ingest`` is deliberately device-free so tenant request handling stays
 cheap; all device work happens in ``flush`` (and is shared across tenants).
 ``poll()`` evaluates the registered continuous queries against one shared
-snapshot -- the batched continuous-query path.
+snapshot through the query planner -- the batched continuous-query path.
+Each stage has one path: the fused ingest scan, the batched query engine,
+the planned poll.  Their conformance oracles are plain functions the tests
+call (``sjpc.update`` replayed with ``ingest_key``, ``reference_snapshot``).
 """
 from __future__ import annotations
 
@@ -53,12 +57,6 @@ class ServiceConfig:
     batch_rows: int = 256            # ingest round size per stream
     window_epochs: int | None = 8    # default; per-stream override at create
     auto_flush_rows: int | None = None   # flush() when a group's backlog hits this
-    use_pallas: bool | None = None   # None = auto (Pallas on TPU)
-    interpret: bool | None = None    # forwarded to the Pallas path
-    use_fused: bool = True           # fused ingest path; False = reference oracle
-    shards: int = 1                  # data-parallel ingest shards per round
-    use_fused_query: bool = True     # batched query engine; False = per-stream
-                                     # numpy oracle (DESIGN.md §12)
     estimator: str = "sjpc"          # default estimator kind for new streams
                                      # (any repro.estimators kind; per-stream
                                      # override at create_stream)
@@ -72,11 +70,8 @@ class ServiceConfig:
     audit_max_records: int = 65536   # audit skip threshold (exact oracle cost)
     trace_sink: object = None        # JSON-lines span sink: path or file-like
     trace_annotate: bool = False     # bracket spans in jax.profiler annotations
-    use_planner: bool = True         # plan poll() through the query planner
-                                     # (cross-group fusion + admission,
-                                     # DESIGN.md §16); False = the PR 3
-                                     # per-group prefetch path
-    planner: PlannerConfig = PlannerConfig()   # fusion/budget knobs
+    planner: PlannerConfig = PlannerConfig()   # poll() fusion/budget knobs
+                                               # (DESIGN.md §16)
 
 
 class EstimationService:
@@ -92,16 +87,10 @@ class EstimationService:
                 max_records=cfg.audit_max_records))
         self.obs = obs
         self.registry = StreamRegistry(obs=self.obs)
-        self.engine = QueryEngine(self.registry,
-                                  use_fused_query=cfg.use_fused_query,
-                                  use_pallas=cfg.use_pallas,
-                                  interpret=cfg.interpret,
-                                  obs=self.obs)
+        self.engine = QueryEngine(self.registry, obs=self.obs)
         self._pipelines: dict[str, IngestPipeline] = {}
         self._continuous: dict[str, ContinuousQuery] = {}
-        self.planner = (QueryPlanner(self.registry, cfg.planner,
-                                     obs=self.obs)
-                        if cfg.use_planner else None)
+        self.planner = QueryPlanner(self.registry, cfg.planner, obs=self.obs)
         self.stats = {"ingested_records": 0, "flush_s": 0.0, "epochs": 0,
                       "snapshots": 0, "polls": 0}
 
@@ -122,21 +111,9 @@ class EstimationService:
 
     # -- provisioning ---------------------------------------------------
     def create_group(self, group_id: str, cfg: SJPCConfig) -> HashGroup:
-        group = self.registry.create_group(
-            group_id, cfg,
-            estimator_opts={
-                "sjpc": {"use_fused": self.cfg.use_fused,
-                         "use_pallas": self.cfg.use_pallas,
-                         "interpret": self.cfg.interpret,
-                         "shards": self.cfg.shards},
-                "reservoir": {"use_pallas": self.cfg.use_pallas,
-                              "interpret": self.cfg.interpret},
-            })
+        group = self.registry.create_group(group_id, cfg)
         self._pipelines[group_id] = IngestPipeline(
-            group, batch_rows=self.cfg.batch_rows,
-            use_pallas=self.cfg.use_pallas, interpret=self.cfg.interpret,
-            use_fused=self.cfg.use_fused, shards=self.cfg.shards,
-            obs=self.obs)
+            group, batch_rows=self.cfg.batch_rows, obs=self.obs)
         return group
 
     def create_stream(self, name: str, group_id: str,
@@ -310,37 +287,27 @@ class EstimationService:
         if query.kind == "join":
             self.registry.require_joinable(*query.streams)
         self._continuous[query.name] = query
-        if self.planner is not None:
-            self.planner.invalidate_queries()
+        self.planner.invalidate_queries()
 
     def set_tenant_budget(self, tenant: str, refill: float | None, *,
                           burst: float | None = None) -> None:
         """Set (or clear) one tenant's per-poll standing-query budget; see
-        :meth:`QueryPlanner.set_tenant_budget`.  Requires the planner."""
-        if self.planner is None:
-            raise ValueError("admission control needs use_planner=True")
+        :meth:`QueryPlanner.set_tenant_budget`."""
         self.planner.set_tenant_budget(tenant, refill, burst=burst)
 
     def poll(self) -> dict[str, QueryResult | dict[int, QueryResult]]:
         """Evaluate every continuous query against ONE shared snapshot.
 
-        With the planner (the default) the device work is scheduled through
-        the cached fusion plan: matching cohorts across hash groups share
-        one ``estimate_batch`` launch, launches run in priority order, and
+        The device work is scheduled through the query planner's cached
+        fusion plan: matching cohorts across hash groups share one
+        ``estimate_batch`` launch, launches run in priority order, and
         over-budget tenants are served their last fresh result with
-        ``stale=True`` (DESIGN.md §16).  With ``use_planner=False`` the
-        PR 3 path prefetches one batch per touched group instead.  Either
-        way the individual ``evaluate`` calls are pure cache lookups.
+        ``stale=True`` (DESIGN.md §16).  The individual ``evaluate`` calls
+        are then pure cache lookups.
         """
         with self.obs.span("service.poll", histogram="service_poll_seconds",
                            queries=len(self._continuous)):
-            snap = self.snapshot()
-            if self.planner is not None:
-                out = self.planner.poll(snap, self._continuous)
-            else:
-                snap.prefetch(self._continuous.values())
-                out = {name: q.evaluate(snap)
-                       for name, q in self._continuous.items()}
+            out = self.planner.poll(self.snapshot(), self._continuous)
             self.stats["polls"] += 1
         if self.obs.auditor is not None:
             for q in self._continuous.values():

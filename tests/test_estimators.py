@@ -160,7 +160,7 @@ class TestServedSideBySide:
         the per-stream reference oracle agree for all of them, and poll()
         returns every stream's standing query from one snapshot."""
         from repro.service import (ContinuousQuery, EstimationService,
-                                   QueryEngine, ServiceConfig)
+                                   ServiceConfig, reference_snapshot)
         svc = EstimationService(ServiceConfig(batch_rows=128,
                                               window_epochs=None))
         svc.create_group("g", CFG)
@@ -173,7 +173,7 @@ class TestServedSideBySide:
                 ContinuousQuery(f"q/{kind}", "self_join", (f"t/{kind}",)))
         res = svc.poll()
         assert set(res) == {f"q/{kind}" for kind in KINDS}
-        ref = QueryEngine(svc.registry, use_fused_query=False).snapshot()
+        ref = reference_snapshot(svc.registry)
         for kind in KINDS:
             nm = f"t/{kind}"
             fused = svc.engine.snapshot([nm]).self_join(nm)
@@ -192,6 +192,12 @@ class TestServedSideBySide:
         svc.create_stream("b", "g", estimator="reservoir")
         with pytest.raises(ValueError, match="join-capable"):
             svc.snapshot().join("a", "b")
+
+    def test_sjpc_rejects_an_estimator_cfg(self):
+        """SJPC's config is the group's SJPCConfig: a per-stream override
+        is refused, not silently dropped."""
+        with pytest.raises(ValueError, match="no estimator_cfg"):
+            E.make("sjpc", CFG, estimator_cfg={"width": 64})
 
 
 class TestAlgebraProperties:

@@ -9,8 +9,8 @@ Three layers, each against an independent oracle:
   estimator  sjpc.estimate_batch / estimate_join_batch vs per-stream
              sjpc.estimate / estimate_join loops (bit-equal here: every
              intermediate is an exact-integer f32);
-  service    the batched Snapshot (use_fused_query=True, the default) vs
-             the per-stream numpy reference path within 1e-6.
+  service    the batched Snapshot vs ``reference_snapshot``, the
+             per-stream numpy reference, within 1e-6.
 """
 import numpy as np
 import jax
@@ -22,7 +22,8 @@ from repro.core import sketch as sk
 from repro.core.sjpc import SJPCConfig
 from repro.kernels.fused_query import fused_query_pallas
 from repro.kernels.ops import fused_query
-from repro.service import EstimationService, QueryEngine, ServiceConfig
+from repro.service import (EstimationService, ServiceConfig,
+                           reference_snapshot)
 
 # shape/depth grids and builders shared with the registry conformance
 # matrix (kernel_cases.py / test_kernel_registry.py)
@@ -149,10 +150,9 @@ class TestSnapshotConformance:
     """The batched Snapshot (service default) == the per-stream reference
     path, every stream x threshold cell, within 1e-6."""
 
-    def _service(self, use_fused_query):
+    def _service(self):
         cfg = SJPCConfig(d=5, s=3, ratio=0.5, width=512, depth=3, seed=51)
-        svc = EstimationService(ServiceConfig(batch_rows=64, window_epochs=3,
-                                              use_fused_query=use_fused_query))
+        svc = EstimationService(ServiceConfig(batch_rows=64, window_epochs=3))
         svc.create_group("g", cfg)
         rng = np.random.default_rng(13)
         names = [f"t{i}" for i in range(5)]
@@ -168,9 +168,8 @@ class TestSnapshotConformance:
         return cfg, svc, names
 
     def test_batched_snapshot_matches_reference(self):
-        cfg, svc, names = self._service(use_fused_query=True)
-        ref_engine = QueryEngine(svc.registry, use_fused_query=False)
-        snap, ref = svc.snapshot(), ref_engine.snapshot()
+        cfg, svc, names = self._service()
+        snap, ref = svc.snapshot(), reference_snapshot(svc.registry)
         for nm in names:
             for k in range(cfg.s, cfg.d + 1):
                 a, b = snap.self_join(nm, k), ref.self_join(nm, k)
@@ -191,9 +190,8 @@ class TestSnapshotConformance:
                                        rtol=1e-6, atol=1e-6)
 
     def test_unclamped_queries_match_too(self):
-        cfg, svc, names = self._service(use_fused_query=True)
-        ref_engine = QueryEngine(svc.registry, use_fused_query=False)
-        snap, ref = svc.snapshot(), ref_engine.snapshot()
+        cfg, svc, names = self._service()
+        snap, ref = svc.snapshot(), reference_snapshot(svc.registry)
         for nm in names[:2]:
             for k in (cfg.s, cfg.d):
                 a = snap.self_join(nm, k, clamp=False)
@@ -204,7 +202,7 @@ class TestSnapshotConformance:
     def test_all_thresholds_single_compiled_batch(self):
         """all_thresholds over every stream shares ONE cached batch entry
         (the one-compiled-call contract)."""
-        _, svc, names = self._service(use_fused_query=True)
+        _, svc, names = self._service()
         snap = svc.snapshot()
         for nm in names:
             snap.all_thresholds(nm)
@@ -213,7 +211,7 @@ class TestSnapshotConformance:
 
     def test_poll_prefetches_joins_in_one_batch(self):
         from repro.service import ContinuousQuery
-        _, svc, names = self._service(use_fused_query=True)
+        _, svc, names = self._service()
         svc.register_continuous(ContinuousQuery("j01", "join",
                                                 (names[0], names[1])))
         svc.register_continuous(ContinuousQuery("j23", "join",
@@ -222,7 +220,7 @@ class TestSnapshotConformance:
                                                 (names[4],)))
         out = svc.poll()
         assert set(out) == {"j01", "j23", "sj"}
-        ref = QueryEngine(svc.registry, use_fused_query=False).snapshot()
+        ref = reference_snapshot(svc.registry)
         assert out["j01"].estimate == pytest.approx(
             ref.join(names[0], names[1]).estimate, rel=1e-6, abs=1e-6)
 

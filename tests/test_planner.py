@@ -1,7 +1,7 @@
 """Query planner + admission control (DESIGN.md §16) and the PR 7 bug
-batch: planner-on results equal planner-off results for every estimator
-kind (self + join), plans cache and invalidate on topology changes,
-throttled tenants get stale=True copies of their last fresh results, and
+batch: planned polls equal a cold engine's lazy snapshot and the
+per-stream reference for every estimator kind (self + join), plans cache
+and invalidate on topology changes, throttled tenants get stale=True copies of their last fresh results, and
 the three query/ingest-path regressions stay fixed -- join prefetch
 buckets by estimator instance, cache eviction is LRU (hot standing-query
 entries survive), and a stream's replay coordinate is independent of its
@@ -16,7 +16,7 @@ from repro.core.sjpc import SJPCConfig
 from repro.estimators.sjpc_backend import SJPCEstimator
 from repro.obs import MetricsRegistry, Observability, Tracer
 from repro.service import (ContinuousQuery, EstimationService, PlannerConfig,
-                           QueryEngine, ServiceConfig)
+                           QueryEngine, ServiceConfig, reference_snapshot)
 
 KINDS = ["sjpc", "reservoir", "lsh_ss"]
 
@@ -79,28 +79,27 @@ def _populate(svc, *, groups=2, rng_seed=0):
 
 
 class TestPlannerConformance:
-    """Planner-on == planner-off within 1e-6 for every served estimate,
-    across all estimator kinds, self + all-thresholds + join, over polls
-    that interleave fresh ingest (the acceptance criterion)."""
+    """Planned polls == the same queries evaluated one by one within 1e-6
+    for every served estimate, across all estimator kinds, self +
+    all-thresholds + join, over polls that interleave fresh ingest (the
+    acceptance criterion).  The oracle is a cold engine's lazy snapshot
+    (per-group batches, no planner) or the per-stream reference."""
 
-    @pytest.mark.parametrize("fused", [True, False])
-    def test_on_equals_off_all_kinds(self, fused):
-        on = EstimationService(ServiceConfig(
-            batch_rows=64, window_epochs=4, use_planner=True,
-            use_fused_query=fused), obs=_obs())
-        off = EstimationService(ServiceConfig(
-            batch_rows=64, window_epochs=4, use_planner=False,
-            use_fused_query=fused), obs=_obs())
-        rng_on = _populate(on)
-        rng_off = _populate(off)
+    @pytest.mark.parametrize("oracle", ["lazy_snapshot", "reference"])
+    def test_on_equals_off_all_kinds(self, oracle):
+        svc = EstimationService(ServiceConfig(
+            batch_rows=64, window_epochs=4), obs=_obs())
+        rng = _populate(svc)
         for _ in range(2):
-            out_on, out_off = on.poll(), off.poll()
-            assert out_on.keys() == out_off.keys()
-            for name in out_on:
-                _result_close(out_on[name], out_off[name])
-            for svc, rng in ((on, rng_on), (off, rng_off)):
-                for g in range(2):
-                    svc.ingest(f"g{g}-sjpc", _records(rng, 100))
+            out = svc.poll()
+            snap = (QueryEngine(svc.registry, obs=_obs()).snapshot()
+                    if oracle == "lazy_snapshot"
+                    else reference_snapshot(svc.registry))
+            assert out.keys() == svc._continuous.keys()
+            for name, q in svc._continuous.items():
+                _result_close(out[name], q.evaluate(snap))
+            for g in range(2):
+                svc.ingest(f"g{g}-sjpc", _records(rng, 100))
 
     def test_cross_group_fusion_one_launch(self):
         """N same-config groups' sjpc cohorts must share ONE
@@ -143,27 +142,16 @@ class TestPlannerConformance:
         reuse = obs.metrics.series("planner_plan_reuse_total")
         assert built[()] == 1.0 and reuse[()] == 1.0
         # a mid-life create_stream changes cohort membership: the plan must
-        # rebuild, and the new stream's results must match a planner-off twin
+        # rebuild, and the new stream's results must match a cold engine's
+        # lazy snapshot
         svc.create_stream("s1", "g")
         svc.ingest("s1", _records(rng, 150))
         svc.register_continuous(ContinuousQuery("q1", "self_join", ("s1",)))
         out = svc.poll()
         assert obs.metrics.series("planner_plans_built_total")[()] == 2.0
-        twin = EstimationService(ServiceConfig(
-            batch_rows=64, window_epochs=4, use_planner=False), obs=_obs())
-        rng = np.random.default_rng(4)
-        twin.create_group("g", _cfg())
-        twin.create_stream("s0", "g")
-        twin.ingest("s0", _records(rng, 200))
-        twin.register_continuous(ContinuousQuery("q0", "self_join", ("s0",)))
-        twin.poll()
-        twin.poll()
-        twin.create_stream("s1", "g")
-        twin.ingest("s1", _records(rng, 150))
-        twin.register_continuous(ContinuousQuery("q1", "self_join", ("s1",)))
-        tout = twin.poll()
+        cold = QueryEngine(svc.registry, obs=_obs()).snapshot()
         for name in out:
-            _result_close(out[name], tout[name])
+            _result_close(out[name], cold.self_join(name.replace("q", "s")))
 
 
 class TestAdmissionControl:
@@ -260,11 +248,9 @@ def scaled_kind():
     try:
         est_mod.register(
             "sjpc_scaled",
-            lambda sjpc_cfg, *, params=None, estimator_cfg=None, opts=None:
+            lambda sjpc_cfg, *, params=None, estimator_cfg=None:
             _ScaledJoinEstimator(sjpc_cfg, params,
-                                 **{**(dict(opts) if opts else {}),
-                                    **(dict(estimator_cfg)
-                                       if estimator_cfg else {})}))
+                                 **dict(estimator_cfg or {})))
     except ValueError:
         pass                         # already registered in this process
     yield "sjpc_scaled"
@@ -277,12 +263,11 @@ class TestJoinPrefetchCohorts:
     alone -- a group mixing estimator_cfg-overridden streams used to
     stack every pair into the first pair's estimator."""
 
-    @pytest.mark.parametrize("use_planner", [True, False])
+    @pytest.mark.parametrize("path", ["planner_poll", "lazy_snapshot"])
     def test_mixed_instance_pairs_answer_with_their_own_estimator(
-            self, use_planner, scaled_kind):
+            self, path, scaled_kind):
         svc = EstimationService(ServiceConfig(
-            batch_rows=64, window_epochs=4, use_planner=use_planner),
-            obs=_obs())
+            batch_rows=64, window_epochs=4), obs=_obs())
         svc.create_group("g", _cfg(ratio=1.0, width=512))
         rng = np.random.default_rng(6)
         for name in ("a1", "b1"):
@@ -296,7 +281,11 @@ class TestJoinPrefetchCohorts:
             ContinuousQuery("j1", "join", ("a1", "b1")))
         svc.register_continuous(
             ContinuousQuery("j2", "join", ("a2", "b2")))
-        out = svc.poll()
+        if path == "planner_poll":
+            out = svc.poll()
+        else:
+            snap = svc.snapshot()
+            out = {"j1": snap.join("a1", "b1"), "j2": snap.join("a2", "b2")}
         # the oracle: each pair alone, through a fresh engine (single-pair
         # launches always use the pair's own estimator)
         for qname, pair in (("j1", ("a1", "b1")), ("j2", ("a2", "b2"))):

@@ -23,7 +23,8 @@ from repro.core import exact
 from repro.core.sjpc import SJPCConfig
 from repro.distributed import harness
 from repro.obs import MetricsRegistry, Observability, Tracer
-from repro.service import ContinuousQuery, EstimationService, ServiceConfig
+from repro.service import (ContinuousQuery, EstimationService, ServiceConfig,
+                           reference_snapshot)
 
 CFG = SJPCConfig(d=5, s=3, ratio=1.0, width=128, depth=2, seed=31)
 PLUGIN_KINDS = ("theta_kmv", "ipf")
@@ -72,8 +73,8 @@ class TestPluginRegistry:
                 before[kind].state_cls.__name__
 
     def test_conflicting_reregistration_names_both_parties(self):
-        def other_factory(cfg, *, params=None, estimator_cfg=None,
-                          opts=None):                        # pragma: no cover
+        def other_factory(cfg, *, params=None,
+                          estimator_cfg=None):               # pragma: no cover
             raise AssertionError
 
         with pytest.raises(ValueError) as ei:
@@ -122,14 +123,14 @@ class TestPluginService:
 
     def test_ipf_join_fused_matches_ref(self):
         recs_a, recs_b = _records(300), _records(200, np.random.default_rng(4))
+        svc, _ = _service()
+        svc.create_stream("a", "g", estimator="ipf")
+        svc.create_stream("b", "g", estimator="ipf")
+        svc.ingest("a", recs_a)
+        svc.ingest("b", recs_b)
         results = {}
-        for fused in (True, False):
-            svc, _ = _service(use_fused_query=fused)
-            svc.create_stream("a", "g", estimator="ipf")
-            svc.create_stream("b", "g", estimator="ipf")
-            svc.ingest("a", recs_a)
-            svc.ingest("b", recs_b)
-            snap = svc.snapshot()
+        for fused, snap in ((True, svc.snapshot()),
+                            (False, reference_snapshot(svc.registry))):
             results[fused] = [snap.join("a", "b", s=s).estimate
                               for s in range(CFG.s, CFG.d + 1)]
         assert results[True] == pytest.approx(results[False], rel=1e-6)

@@ -3,8 +3,7 @@ launch keeps its stacked states across polls and restacks only the rows
 whose window state changed.  Whatever path a poll takes -- incremental
 update, full rebuild, membership change, a stream re-created under its old
 name -- the resident stack equals ``stack_states`` over the snapshot's
-views bit for bit, and every answer equals a fresh engine's and the
-planner-free path's."""
+views bit for bit, and every answer equals a fresh engine's."""
 from __future__ import annotations
 
 import logging
@@ -28,12 +27,12 @@ def _records(rng, n=96):
     return rng.integers(0, 40, size=(n, CFG.d), dtype=np.uint32)
 
 
-def _service(kind: str, *, use_planner: bool = True):
+def _service(kind: str):
     reg = MetricsRegistry()
     obs = Observability(metrics=reg, tracer=Tracer(registry=reg))
     # window_epochs=1: every advance_epoch expires every window
-    svc = EstimationService(ServiceConfig(batch_rows=64, window_epochs=1,
-                                          use_planner=use_planner), obs=obs)
+    svc = EstimationService(ServiceConfig(batch_rows=64, window_epochs=1),
+                            obs=obs)
     svc.create_group("g", CFG)
     for i in range(N):
         svc.create_stream(f"t{i:02d}", "g", estimator=kind)
@@ -119,29 +118,22 @@ STEPS = [("prefill", _change(N), [("full", N)]),
 
 @pytest.mark.parametrize("kind", ["sjpc", "reservoir", "lsh_ss"])
 def test_resident_stack_equals_a_fresh_stack_after_every_poll(kind):
-    planned, plain = _service(kind), _service(kind, use_planner=False)
-    rngs = {id(planned): np.random.default_rng(11),
-            id(plain): np.random.default_rng(11)}
+    svc = _service(kind)
+    rng = np.random.default_rng(11)
     for label, step, want in STEPS:
-        outs = []
-        for svc in (planned, plain):
-            n0 = len(svc.obs.tracer.events)
-            step(svc, rngs[id(svc)])
-            outs.append(svc.poll())
-            got = [(e["mode"], e["changed"])
-                   for e in list(svc.obs.tracer.events)[n0:]
-                   if e["name"] == "query.stack"]
-            assert got == want, (label, got)
-            res, views = _resident(svc)
-            assert res.names == tuple(v.name for v in views)
-            _assert_bitwise(res.stacked,
-                            stack_states([v.state for v in views]))
-        fresh = QueryEngine(planned.registry,
-                            use_pallas=planned.engine.use_pallas,
-                            interpret=planned.engine.interpret).snapshot()
-        for name, q in planned._continuous.items():
-            _assert_same_answer(outs[0][name], outs[1][name])
-            _assert_same_answer(outs[0][name], q.evaluate(fresh))
+        n0 = len(svc.obs.tracer.events)
+        step(svc, rng)
+        out = svc.poll()
+        got = [(e["mode"], e["changed"])
+               for e in list(svc.obs.tracer.events)[n0:]
+               if e["name"] == "query.stack"]
+        assert got == want, (label, got)
+        res, views = _resident(svc)
+        assert res.names == tuple(v.name for v in views)
+        _assert_bitwise(res.stacked, stack_states([v.state for v in views]))
+        fresh = QueryEngine(svc.registry).snapshot()
+        for name, q in svc._continuous.items():
+            _assert_same_answer(out[name], q.evaluate(fresh))
 
 
 # -- the mechanism --------------------------------------------------------

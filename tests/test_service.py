@@ -11,11 +11,27 @@ from repro.core import sjpc
 from repro.core.sjpc import SJPCConfig
 from repro.service import (ContinuousQuery, EstimationService, ServiceConfig,
                            MonitorServiceClient, ingest_key)
-from repro.service.ingest import multi_stream_update
+from repro.service.ingest import multi_round_update
 
 
 def _records(rng, n, d, card=6):
     return rng.integers(0, card, size=(n, d)).astype(np.uint32)
+
+
+def _replay_flush(cfg, params, uid, rows, batch_rows):
+    """One flush of ``rows`` rebuilt offline: per-level ``sjpc.update``
+    over the pipeline's padded, masked rounds and ``ingest_key`` keys."""
+    _, ref = sjpc.init(cfg)
+    for r in range(-(-rows.shape[0] // batch_rows)):
+        chunk = rows[r * batch_rows:(r + 1) * batch_rows]
+        padded = np.zeros((batch_rows, cfg.d), np.uint32)
+        padded[:chunk.shape[0]] = chunk
+        mask = np.zeros((batch_rows,), np.int32)
+        mask[:chunk.shape[0]] = 1
+        ref = sjpc.update(cfg, params, ref, jnp.asarray(padded),
+                          key=ingest_key(cfg, uid, r),
+                          row_mask=jnp.asarray(mask))
+    return ref
 
 
 class TestMergeSemantics:
@@ -69,8 +85,9 @@ class TestMultiStreamUpdate:
         assert float(masked.n) == 20.0
 
     def test_batched_equals_per_stream_loop(self):
-        """ratio<1: one vmapped dispatch == S separate sjpc.update calls
-        given the same keys and masks."""
+        """ratio<1: one vmapped dispatch (a single-round
+        ``multi_round_update``) == S separate sjpc.update calls given the
+        same keys and masks."""
         cfg = SJPCConfig(d=4, s=2, ratio=0.5, width=512, depth=2, seed=5)
         params, s0 = sjpc.init(cfg)
         rng = np.random.default_rng(3)
@@ -82,9 +99,9 @@ class TestMultiStreamUpdate:
         counters = jnp.stack([s0.counters] * S)
         n = jnp.stack([s0.n] * S)
         steps = jnp.stack([s0.step] * S)
-        bc, bn, bs = multi_stream_update(cfg, params, counters, n, steps,
-                                         jnp.asarray(values),
-                                         jnp.asarray(mask), keys)
+        bc, bn, bs = multi_round_update(cfg, params, counters, n, steps,
+                                        jnp.asarray(values)[None],
+                                        jnp.asarray(mask)[None], keys[None])
         for i in range(S):
             ref = sjpc.update(cfg, params, s0, jnp.asarray(values[i]),
                               key=keys[i], row_mask=jnp.asarray(mask[i]))
@@ -111,17 +128,7 @@ class TestMultiStreamUpdate:
         group = svc.registry.group("g")
         for name in sizes:
             entry = svc.registry.stream(name)
-            _, ref = sjpc.init(cfg)
-            rows = data[name]
-            for r in range((rows.shape[0] + 31) // 32):
-                chunk = rows[r * 32:(r + 1) * 32]
-                padded = np.zeros((32, 4), np.uint32)
-                padded[:chunk.shape[0]] = chunk
-                mask = np.zeros((32,), np.int32)
-                mask[:chunk.shape[0]] = 1
-                ref = sjpc.update(cfg, group.params, ref, jnp.asarray(padded),
-                                  key=ingest_key(cfg, entry.uid, r),
-                                  row_mask=jnp.asarray(mask))
+            ref = _replay_flush(cfg, group.params, entry.uid, data[name], 32)
             np.testing.assert_array_equal(
                 np.asarray(entry.window.total.counters),
                 np.asarray(ref.counters), err_msg=name)
@@ -129,59 +136,28 @@ class TestMultiStreamUpdate:
 
 
 class TestFusedServicePaths:
-    """The rewired pipeline: fused default == reference oracle bit-exactly,
-    and the sharded flush == per-shard replay with the pipeline's keys."""
-
-    def _ingest_all(self, sc, cfg, recs):
-        svc = EstimationService(sc)
-        svc.create_group("g", cfg)
-        for nm, rows in recs.items():
-            svc.create_stream(nm, "g")
-            svc.ingest(nm, rows)
-        svc.flush()
-        return svc
+    """The fused flush == the per-level reference ``sjpc.update`` replayed
+    per stream with the pipeline's keys, bit-exactly."""
 
     def test_fused_flush_equals_oracle_flush(self):
         cfg = SJPCConfig(d=4, s=2, ratio=0.5, width=512, depth=2, seed=41)
         rng = np.random.default_rng(9)
         recs = {"a": _records(rng, 50, 4), "b": _records(rng, 20, 4)}
-        fused = self._ingest_all(
-            ServiceConfig(batch_rows=32, window_epochs=None), cfg, recs)
-        oracle = self._ingest_all(
-            ServiceConfig(batch_rows=32, window_epochs=None, use_fused=False),
-            cfg, recs)
-        for nm in recs:
-            np.testing.assert_array_equal(
-                np.asarray(fused.registry.stream(nm).window.total.counters),
-                np.asarray(oracle.registry.stream(nm).window.total.counters),
-                err_msg=nm)
-
-    def test_sharded_flush_equals_per_shard_replay(self):
-        cfg = SJPCConfig(d=4, s=2, ratio=0.5, width=512, depth=2, seed=43)
-        rng = np.random.default_rng(10)
-        rows = _records(rng, 50, 4)
-        svc = self._ingest_all(
-            ServiceConfig(batch_rows=32, window_epochs=None, shards=2),
-            cfg, {"a": rows})
-        entry = svc.registry.stream("a")
+        svc = EstimationService(ServiceConfig(batch_rows=32,
+                                              window_epochs=None))
+        svc.create_group("g", cfg)
+        for nm, rows in recs.items():
+            svc.create_stream(nm, "g")
+            svc.ingest(nm, rows)
+        svc.flush()
         params = svc.registry.group("g").params
-        shard_states = [sjpc.init(cfg)[1] for _ in range(2)]
-        for r in range(2):                       # 50 rows -> 2 rounds of 32
-            chunk = rows[r * 32:(r + 1) * 32]
-            padded = np.zeros((32, 4), np.uint32)
-            padded[:chunk.shape[0]] = chunk
-            mask = np.zeros((32,), np.int32)
-            mask[:chunk.shape[0]] = 1
-            rkey = ingest_key(cfg, entry.uid, r)
-            for j in range(2):                   # shard j gets rows [16j, 16j+16)
-                shard_states[j] = sjpc.update(
-                    cfg, params, shard_states[j], padded[j * 16:(j + 1) * 16],
-                    key=jax.random.fold_in(rkey, j),
-                    row_mask=mask[j * 16:(j + 1) * 16])
-        want = sjpc.merge(shard_states[0], shard_states[1])
-        np.testing.assert_array_equal(
-            np.asarray(entry.window.total.counters), np.asarray(want.counters))
-        assert float(entry.window.total.n) == 50.0 == float(want.n)
+        for nm, rows in recs.items():
+            entry = svc.registry.stream(nm)
+            want = _replay_flush(cfg, params, entry.uid, rows, 32)
+            np.testing.assert_array_equal(
+                np.asarray(entry.window.total.counters),
+                np.asarray(want.counters), err_msg=nm)
+            assert float(entry.window.total.n) == float(want.n)
 
 
 def _run_epochs(svc, cfg, name, epoch_batches):
@@ -395,27 +371,32 @@ class TestSnapshotCacheInvalidation:
         svc.flush()
         assert win.version == v3
 
-    @pytest.mark.parametrize("use_fused_query", [True, False])
-    def test_snapshot_across_expiry_boundary_not_stale(self, use_fused_query):
+    @pytest.mark.parametrize("query", ["self_join", "join"])
+    def test_snapshot_across_expiry_boundary_not_stale(self, query):
+        """Self-join cache keys embed the stream's window version, join
+        keys both streams' versions: neither may serve a stale entry."""
         from repro.service import QueryEngine
         cfg, svc = self._build()
-        svc.cfg = ServiceConfig(batch_rows=16, window_epochs=2,
-                                use_fused_query=use_fused_query)
-        svc.engine = QueryEngine(svc.registry,
-                                 use_fused_query=use_fused_query)
+        names = ("a",) if query == "self_join" else ("a", "b")
+        if query == "join":
+            svc.create_stream("b", "g")
+
+        def ask(snap):
+            return getattr(snap, query)(*names)
+
         rng = np.random.default_rng(5)
-        svc.ingest("a", _records(rng, 24, 4))
+        for nm in names:
+            svc.ingest(nm, _records(rng, 24, 4))
         svc.advance_epoch()
-        before = svc.snapshot().self_join("a")      # fills the shared cache
+        before = ask(svc.snapshot())                # fills the shared cache
         # two more epochs: the first epoch's records expire out of the window
         for _ in range(2):
-            svc.ingest("a", _records(rng, 24, 4))
+            for nm in names:
+                svc.ingest(nm, _records(rng, 24, 4))
             svc.advance_epoch()
-        after = svc.snapshot().self_join("a")
+        after = ask(svc.snapshot())
         # independent engine with a COLD cache = ground truth
-        fresh = QueryEngine(svc.registry,
-                            use_fused_query=use_fused_query) \
-            .snapshot().self_join("a")
+        fresh = ask(QueryEngine(svc.registry).snapshot())
         assert after.estimate == fresh.estimate
         np.testing.assert_array_equal(after.per_level, fresh.per_level)
         # the window really changed, so a stale cache hit would have been
